@@ -42,6 +42,10 @@ from benchmarks import _seed_des_baseline as seed_des
 
 _GOLDENS = os.path.join(_REPO_ROOT, "tests", "data", "seed_fig_goldens.json")
 
+#: Every time below is host wall seconds: the DES is host Python, and the
+#: Pallas solver runs interpreted unless JAX's platform is the TPU.
+_CLOCK = "host CPU wall seconds; Pallas interpreted off-TPU"
+
 
 def _time(fn) -> float:
     t0 = time.perf_counter()
@@ -153,6 +157,31 @@ _SWEEP1K_MAX_FLIPS = 12
 _SWEEP1K_P95_BOUND = 0.08
 
 
+def lane_gate(ref, cand) -> dict:
+    """The kilo-grid gate of ``cand`` against ``ref``, two runs of one grid.
+
+    Each is a per-cell sequence of ``(restricted, {workload: GB/s})``:
+    whether MIKU restricted the cell, and its bandwidths.  Cells whose
+    decision differs count as flips; the rest give the aligned worst-of-
+    workloads relative bandwidth deviations."""
+    errs, flips = [], 0
+    for (r_ref, bw_ref), (r, bw) in zip(ref, cand):
+        if r_ref != r:
+            flips += 1
+            continue
+        errs.append(max(abs(bw[w] - bw_ref[w]) / max(bw_ref[w], 1e-9)
+                        for w in bw_ref))
+    errs.sort()
+    p95 = errs[int(0.95 * (len(errs) - 1))] if errs else 0.0
+    return {
+        "decision_flip_cells": flips,
+        "aligned_p95_rel_err": p95,
+        "aligned_worst_rel_err": errs[-1] if errs else 0.0,
+        "within_gate": (flips <= _SWEEP1K_MAX_FLIPS
+                        and p95 <= _SWEEP1K_P95_BOUND),
+    }
+
+
 def bench_sweep_1k() -> dict:
     """Kilo-cell lane A/B/C: the 1024-cell ``corun_sweep_1k`` grid on the
     scalar pool, the batched numpy lane, and the batched lane with the
@@ -193,33 +222,13 @@ def bench_sweep_1k() -> dict:
     scalar = run_sweep(jobs, processes=procs, lane="scalar")
     t_scalar = time.perf_counter() - t0
 
-    def _restricted(res) -> bool:
-        return any(d.phase == Phase.RESTRICTED for d in res.decisions)
+    def cells(results):
+        return [(any(d.phase == Phase.RESTRICTED for d in r.decisions),
+                 {w: r.bandwidth(w) for w in ("ddr", "cxl")})
+                for r in results]
 
-    def lane_stats(batched):
-        errs, flips = [], 0
-        for s, b in zip(scalar, batched):
-            e = max(
-                abs(b.bandwidth(w) - s.bandwidth(w))
-                / max(s.bandwidth(w), 1e-9)
-                for w in ("ddr", "cxl")
-            )
-            if _restricted(s) != _restricted(b):
-                flips += 1
-            else:
-                errs.append(e)
-        errs.sort()
-        p95 = errs[int(0.95 * (len(errs) - 1))] if errs else 0.0
-        return {
-            "decision_flip_cells": flips,
-            "aligned_p95_rel_err": round(p95, 4),
-            "aligned_worst_rel_err": round(errs[-1] if errs else 0.0, 4),
-            "within_gate": (flips <= _SWEEP1K_MAX_FLIPS
-                            and p95 <= _SWEEP1K_P95_BOUND),
-        }
-
-    st_np = lane_stats(numpy_res)
-    st_pl = lane_stats(pallas_res)
+    st_np = lane_gate(cells(scalar), cells(numpy_res))
+    st_pl = lane_gate(cells(scalar), cells(pallas_res))
     return {
         "sweep_scenario": "corun_sweep_1k",
         "sweep_cells": len(jobs),
@@ -328,6 +337,9 @@ def main() -> None:
                          "gate on <10%% overhead + bit-identical outcomes — "
                          "the CI gating-lane obs smoke")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     snapshot = os.path.join(_REPO_ROOT, "BENCH_des.json")
     if args.smoke:
         out = {"bench": "des_fast_path_smoke", **bench_ab(2)}
@@ -347,7 +359,7 @@ def main() -> None:
         )
         return
     if args.sweep_1k:
-        out = {"bench": "des_sweep_1k", **bench_sweep_1k()}
+        out = {"bench": "des_sweep_1k", "clock": _CLOCK, **bench_sweep_1k()}
         print(json.dumps(out, indent=2))
         assert out["lanes_within_gate"], (
             f"batched lanes off the scalar DES on the 1024-cell grid "
@@ -357,7 +369,8 @@ def main() -> None:
             print("WARNING: batched lane below the 5x acceptance bar on "
                   "the 1024-cell grid (noisy machine, or a regression)")
         return
-    out = {"bench": "des_fast_path", **bench_ab(args.reps), **check_goldens()}
+    out = {"bench": "des_fast_path", "clock": _CLOCK,
+           **bench_ab(args.reps), **check_goldens()}
     out.update(check_fast_path_overhead(out, snapshot))
     out["sweep_lanes"] = bench_sweep_lanes()
     out["sweep_1k"] = bench_sweep_1k()

@@ -22,10 +22,12 @@
 #      ``name,us_per_call,derived`` CSV:
 #        PYTHONPATH=src python benchmarks/run.py [filter] [--jobs N]
 #
-# ``--jobs N`` runs the figure modules concurrently in a process pool (each
-# module's sweep is itself a batch of independent sims; figure-level
-# parallelism composes with REPRO_SWEEP_PROCS for the in-module sweeps).
-# Output order is deterministic (module order) either way.
+# ``--jobs N`` runs the figure modules concurrently in a spawned process
+# pool (each module's sweep is itself a batch of independent sims;
+# figure-level parallelism composes with REPRO_SWEEP_PROCS for the in-module
+# sweeps).  Modules that need the accelerator run in the parent instead: a
+# chip belongs to one process.  Output order is deterministic (module order)
+# either way, and any ``ERROR:`` row makes the harness exit non-zero.
 #
 # The figure-module list is *derived from the scenario registry* (each
 # scenario names the benchmarks module that presents it), so the registry
@@ -35,9 +37,9 @@
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import sys
-from concurrent.futures import ProcessPoolExecutor
 
 # Allow `python benchmarks/run.py` as well as `python -m benchmarks.run`.
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -60,6 +62,19 @@ def _module_names() -> list:
             mods.append(sc.module)
     mods.extend(_EXTRA_MODULES)
     return mods
+
+
+def _uses_device(name: str) -> bool:
+    """Whether a figure module touches the accelerator: one presenting a
+    ``device`` scenario always does, and every module does when its sweeps
+    run the batched lane on the Pallas solver."""
+    from repro.memsim.batched.kernel import backend
+    from repro.memsim.sweep import default_lane
+    from repro.scenarios import all_scenarios
+
+    return any(sc.device for sc in all_scenarios() if sc.module == name) or (
+        default_lane() == "batched" and backend() == "pallas"
+    )
 
 
 def _run_module(name: str) -> list:
@@ -242,6 +257,9 @@ def main() -> None:
                          "on every simulation; violations raise.  Forces "
                          "the scalar DES.  Equivalent to REPRO_SANITIZE=1.")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.sanitize:
         # The env switch (not a SimJob field) so every sim in the process —
@@ -274,17 +292,28 @@ def main() -> None:
         ap.error("--profile requires --scenario")
 
     from benchmarks.common import emit
+    from repro.memsim.sweep import host_pool
 
     names = [n for n in _module_names()
              if not args.only or args.only in n]
     print("name,us_per_call,derived")
-    if args.jobs > 1 and len(names) > 1:
-        with ProcessPoolExecutor(max_workers=min(args.jobs, len(names))) as pool:
-            for rows in pool.map(_run_module, names):
-                emit(rows)
-    else:
+    pooled = [n for n in names if not _uses_device(n)] \
+        if args.jobs > 1 else []
+    failed = []
+    with contextlib.ExitStack() as stack:
+        futures = {}
+        if len(pooled) > 1:
+            pool = stack.enter_context(host_pool(min(args.jobs, len(pooled))))
+            futures = {n: pool.submit(_run_module, n) for n in pooled}
         for name in names:
-            emit(_run_module(name))
+            rows = futures[name].result() if name in futures \
+                else _run_module(name)
+            emit(rows)
+            failed += [r[0] for r in rows if str(r[2]).startswith("ERROR:")]
+    if failed:
+        print(f"error: {len(failed)} figure row(s) failed: "
+              f"{', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -55,7 +55,6 @@ from repro.core.tiers import (
     HOST_TIER,
     TieredLayout,
     TierSpec,
-    host_offload_supported,
 )
 
 __all__ = [
@@ -105,5 +104,4 @@ __all__ = [
     "HOST_TIER",
     "TieredLayout",
     "TierSpec",
-    "host_offload_supported",
 ]

@@ -5,8 +5,7 @@ Two cooperating pieces:
 * :class:`HostOffloader` — executes *real* JAX device<->host transfers
   (``jax.device_put`` with memory-kind shardings) with double-buffered
   prefetch.  JAX's async dispatch gives natural overlap; ``block()`` fences.
-  On backends without a pinned_host space it degrades to device-resident
-  copies (still exercising the full control path).
+  A backend without a pinned_host space is refused at construction.
 
 * :class:`TransferQueue` — the *timing* model of the shared transfer path
   (per-chip DMA descriptors): a simulated clock charging each transfer its
@@ -21,7 +20,7 @@ Two cooperating pieces:
   default pinned-host path, plus any ``extra_slow`` tiers) gets its own
   in-flight cap and byte-rate.  On real TPU hardware this class would be
   replaced by reading transfer-completion timestamps from the runtime; the
-  control law is unchanged (DESIGN.md §2).
+  control law is unchanged (ARCHITECTURE.md).
 """
 
 from __future__ import annotations
@@ -40,8 +39,7 @@ from repro.core.tiers import (
     HBM_TIER,
     HOST_TIER,
     TierSpec,
-    host_offload_supported,
-    with_memory_kind,
+    require_host_offload,
 )
 
 
@@ -51,14 +49,9 @@ class HostOffloader:
     def __init__(self, sharding: Optional[jax.sharding.Sharding] = None):
         if sharding is None:
             sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-        self._base = sharding
-        self.supported = host_offload_supported()
-        self._host_sharding = (
-            with_memory_kind(sharding, HOST_TIER.memory_kind)
-            if self.supported
-            else sharding
-        )
-        self._device_sharding = with_memory_kind(sharding, HBM_TIER.memory_kind)
+        require_host_offload(next(iter(sharding.device_set)))
+        self._host_sharding = sharding.with_memory_kind(HOST_TIER.memory_kind)
+        self._device_sharding = sharding.with_memory_kind(HBM_TIER.memory_kind)
 
     def to_host(self, tree: Any) -> Any:
         """Offload a pytree to the host tier (async)."""

@@ -1,14 +1,14 @@
 """Memory-tier specifications and JAX memory-kind placement helpers.
 
-The TPU deployment of the paper's tiered memory (DESIGN.md §2): HBM is the
-fast tier (``memory_kind="device"``), pinned host DRAM over PCIe is the slow
-tier (``memory_kind="pinned_host"``).  JAX exposes both through shardings'
-``with_memory_kind``; XLA compiles explicit device<->host transfers for
-arrays annotated this way.
+The TPU deployment of the paper's tiered memory (ARCHITECTURE.md): HBM is
+the fast tier (``memory_kind="device"``), pinned host DRAM over PCIe is the
+slow tier (``memory_kind="pinned_host"``).  JAX exposes both through
+shardings' ``with_memory_kind``; XLA compiles explicit device<->host
+transfers for arrays annotated this way.
 
-These helpers are runtime-agnostic: on CPU-only containers the pinned_host
-memory space exists in recent jaxlibs, and everything degrades gracefully to
-"device" when it does not (``host_offload_supported``).
+The TPU and the CPU backend both offer a ``pinned_host`` memory space.  A
+backend without one is an error for host placement: nothing quietly keeps
+"host" state in device memory.
 """
 
 from __future__ import annotations
@@ -47,22 +47,15 @@ HOST_TIER = TierSpec(
 )
 
 
-def host_offload_supported(device: Optional[jax.Device] = None) -> bool:
-    """True if this backend exposes a pinned_host memory space."""
+def require_host_offload(device: Optional[jax.Device] = None) -> None:
+    """Raise unless ``device`` has a pinned_host memory space."""
     dev = device or jax.devices()[0]
-    try:
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:
-        return False
-    return PINNED_HOST in kinds
-
-
-def with_memory_kind(sharding: jax.sharding.Sharding, kind: str):
-    """Annotate a sharding with a memory kind, if supported."""
-    try:
-        return sharding.with_memory_kind(kind)
-    except Exception:
-        return sharding
+    kinds = sorted(m.kind for m in dev.addressable_memories())
+    if PINNED_HOST not in kinds:
+        raise RuntimeError(
+            f"{dev} has no {PINNED_HOST!r} memory (memories: {kinds}); "
+            "host-tier placement needs one"
+        )
 
 
 def put_on_tier(x, tier: TierSpec, sharding: Optional[jax.sharding.Sharding] = None):
@@ -70,7 +63,7 @@ def put_on_tier(x, tier: TierSpec, sharding: Optional[jax.sharding.Sharding] = N
     if sharding is None:
         dev = jax.devices()[0]
         sharding = jax.sharding.SingleDeviceSharding(dev)
-    return jax.device_put(x, with_memory_kind(sharding, tier.memory_kind))
+    return jax.device_put(x, sharding.with_memory_kind(tier.memory_kind))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,8 +74,7 @@ class TieredLayout:
     the ones decode touches every step); everything older lives on the host
     tier in ``page_tokens``-sized pages fetched on demand.  For
     sliding-window-attention layers the hot window naturally equals the
-    attention window, making SWA models the ideal tiering citizens
-    (DESIGN.md §4).
+    attention window, making SWA models the ideal tiering citizens.
     """
 
     total_tokens: int

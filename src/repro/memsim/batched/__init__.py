@@ -24,7 +24,8 @@ station-service computation instead:
   lane.
 * :mod:`~repro.memsim.batched.kernel` — the per-window equilibrium solver:
   a numpy bisection by default, or a Pallas kernel when
-  ``REPRO_BATCH_BACKEND=pallas`` (``jax.pallas``; interpreted off-TPU).
+  ``REPRO_BATCH_BACKEND=pallas`` (``jax.pallas``; compiled on the TPU,
+  interpreted on the CPU backend).
 
 Entry point: :func:`run_sweep_batched`, normally reached through
 ``run_sweep(jobs, lane="batched")`` / ``benchmarks/run.py --lane batched``.

@@ -26,7 +26,6 @@ once.
 from __future__ import annotations
 
 import math
-import warnings
 from typing import List, Optional
 
 import numpy as np
@@ -140,8 +139,7 @@ def run_fluid(
     tier_frac_live = group.tier_frac.copy()
     effmlp_live = group.effmlp.copy()
     # Under the pallas backend the whole relaxation loop runs as one jit
-    # dispatch per window (kernel.fused_window_solve); a failing jax stack
-    # falls back — once, loudly — to the numpy loop below.
+    # dispatch per window (kernel.fused_window_solve); a failure raises.
     use_fused = kernel.backend() == "pallas"
 
     # Accumulators.
@@ -197,24 +195,14 @@ def run_fluid(
         route_svc = route * svc
 
         # -- equilibrium solve (wait relaxation + water-filling) ----------
-        y = None
         if use_fused:
-            try:
-                y, Wq, lam = kernel.fused_window_solve(
-                    A, y_rate, o_eff, route, route_svc, svc_pipe,
-                    group.slots, group.tor_cap, group.irq_cap, Wq,
-                    _N_OUTER, _DAMP,
-                )
-                coupled = np.isfinite(lam)
-            except Exception as ex:
-                use_fused = False
-                y = None
-                warnings.warn(
-                    f"fused pallas window solver unavailable ({ex!r}); "
-                    "falling back to the numpy relaxation loop",
-                    RuntimeWarning,
-                )
-        if y is None:
+            y, Wq, lam = kernel.fused_window_solve(
+                A, y_rate, o_eff, route, route_svc, svc_pipe,
+                group.slots, group.tor_cap, group.irq_cap, Wq,
+                _N_OUTER, _DAMP,
+            )
+            coupled = np.isfinite(lam)
+        else:
             y = np.zeros((C, W))
             coupled = np.zeros(C, bool)
             R_tor = np.zeros((C, W))

@@ -18,9 +18,11 @@ rate λ (the fluid image of the DES's round-robin core arbitration):
 
 ``global_lambda`` has two backends: numpy (default) and a Pallas kernel
 (``REPRO_BATCH_BACKEND=pallas``) that runs the whole bisection on-device
-(``jax.lax.fori_loop`` inside one ``pl.pallas_call``; interpreted
-automatically off-TPU).  Both produce the same fixed point to float
-tolerance — ``tests/test_batched.py`` pins backend parity.
+(``jax.lax.fori_loop`` inside one ``pl.pallas_call``; compiled for the TPU,
+interpreted on the CPU backend, refused anywhere else).  Both produce the
+same fixed point to float tolerance — ``tests/test_batched.py`` pins
+backend parity.  A failing Pallas backend raises: nothing falls back to
+numpy behind the caller's back.
 
 :func:`fused_window_solve` goes further: under the pallas backend the
 fluid engine hands the *entire* per-window wait-relaxation loop (station
@@ -35,7 +37,6 @@ window becomes O(1) in cell count.
 from __future__ import annotations
 
 import os
-import warnings
 from typing import Optional
 
 import numpy as np
@@ -113,7 +114,27 @@ def _global_lambda_numpy(A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap):
 
 
 _pallas_solver = None
-_pallas_failed = False
+
+
+def interpret_mode(platform: str) -> bool:
+    """Whether Pallas kernels run interpreted on ``platform``.
+
+    Only the CPU backend interprets (tests and host rehearsals); the TPU
+    compiles the kernel.  Any other platform has no kernel to run."""
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas solver runs on 'tpu' (compiled) or 'cpu' "
+        f"(interpreted), not on {platform!r}"
+    )
+
+
+def _default_interpret() -> bool:
+    import jax
+
+    return interpret_mode(jax.default_backend())
 
 
 def _glam_kernel(jax, jnp):
@@ -155,13 +176,12 @@ def _glam_kernel(jax, jnp):
     return kernel
 
 
-def _build_pallas_solver():
-    """Compile the bisection as one Pallas kernel (interpreted off-TPU)."""
+def _build_pallas_solver(interpret: bool):
+    """Compile the bisection as one Pallas kernel."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    interpret = jax.default_backend() != "tpu"
     kernel = _glam_kernel(jax, jnp)
 
     @jax.jit
@@ -180,7 +200,7 @@ def _global_lambda_pallas(A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap):
     import jax.numpy as jnp
 
     if _pallas_solver is None:
-        _pallas_solver = _build_pallas_solver()
+        _pallas_solver = _build_pallas_solver(_default_interpret())
     big = 1e30  # f32-safe stand-in for +inf inputs
     f32 = lambda x: jnp.asarray(np.minimum(x, big), jnp.float32)  # noqa: E731
     hi0 = (np.minimum(cap, big) / np.maximum(A, 1e-12)).max(axis=1) + 1e-6
@@ -208,26 +228,10 @@ def global_lambda(
     bound; ``R_tor`` the per-insert ToR residency; ``irq_cap`` the staging
     queue each queue-builder's MLP partly parks in.  Returns ``(C,)`` λ,
     ``+inf`` where the ToR never fills."""
-    chosen = force_backend or backend()
-    global _pallas_failed
-    if chosen == "pallas" and not _pallas_failed:
-        if force_backend:
-            # Explicitly forced (tests, parity gates): a broken pallas
-            # backend must FAIL, not silently compare numpy to numpy.
-            return _global_lambda_pallas(
-                A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap
-            )
-        try:
-            return _global_lambda_pallas(
-                A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap
-            )
-        except Exception as ex:  # missing/odd jax: fall back, once, loudly
-            _pallas_failed = True
-            warnings.warn(
-                f"REPRO_BATCH_BACKEND=pallas unavailable ({ex!r}); "
-                "falling back to the numpy solver",
-                RuntimeWarning,
-            )
+    if (force_backend or backend()) == "pallas":
+        return _global_lambda_pallas(
+            A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap
+        )
     return _global_lambda_numpy(
         A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap
     )
@@ -236,18 +240,44 @@ def global_lambda(
 _fused_solvers: dict = {}
 
 
-def _build_fused_solver(n_outer: int, damp: float):
+def fused_solver_args(C: int, W: int, S: int, sharding=None) -> tuple:
+    """Shape stand-ins for the fused solver's arguments at ``(C, W, S)``
+    cells × workloads × stations, for lowering it without data."""
+    import jax
+    import jax.numpy as jnp
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    cw, cws = f32(C, W), f32(C, W, S)
+    return (cw, cw, cw, cws, cws, cws, f32(C, S), f32(C, 1), f32(C, 1),
+            f32(C, S))
+
+
+def fused_solver(n_outer: int, damp: float):
+    """The jitted window solver for this platform, built once per setting."""
+    key = (int(n_outer), float(damp))
+    solver = _fused_solvers.get(key)
+    if solver is None:
+        solver = _fused_solvers[key] = build_fused_solver(
+            *key, _default_interpret()
+        )
+    return solver
+
+
+def build_fused_solver(n_outer: int, damp: float, interpret: bool):
     """Compile the whole wait-relaxation loop as one jit function.
 
     The outer loop (``n_outer`` damped iterations), the station bisection,
     and the global-λ Pallas bisection all run inside a single ``jax.jit``
     trace, so the fluid engine pays one dispatch per window regardless of
-    cell count.  f32 throughout with ``1e30`` standing in for ``+inf``."""
+    cell count.  f32 throughout with ``1e30`` standing in for ``+inf``.
+    ``interpret`` comes from the caller (:func:`interpret_mode` of the
+    platform that will run it)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    interpret = jax.default_backend() != "tpu"
     glam_kernel = _glam_kernel(jax, jnp)
     big = 1e30
 
@@ -365,15 +395,11 @@ def fused_window_solve(
     for ``+inf`` rate caps) and come back float64.  Returns ``(y, Wq, lam)``
     with ``lam`` the last iteration's global λ — ``+inf`` where the ToR
     never fills, so ``np.isfinite(lam)`` stays the coupling test.  Raises
-    on any jax failure; the fluid engine catches once, warns, and reruns
-    the numpy loop.
+    on any jax failure.
     """
     import jax.numpy as jnp
 
-    key = (int(n_outer), float(damp))
-    solver = _fused_solvers.get(key)
-    if solver is None:
-        solver = _fused_solvers[key] = _build_fused_solver(*key)
+    solver = fused_solver(n_outer, damp)
     big = 1e30
     f32 = lambda x: jnp.asarray(np.minimum(x, big), jnp.float32)  # noqa: E731
     y, wq, lam = solver(

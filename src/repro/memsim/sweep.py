@@ -3,11 +3,12 @@
 Every figure in the paper is a *matrix* of independent simulations (ops x
 tiers x thread counts x platforms).  :class:`SimJob` is the picklable
 description of one cell; :func:`run_sweep` executes a batch — serially in
-process for small batches, or fanned out over a ``ProcessPoolExecutor`` for
-figure matrices (``processes`` argument, or the ``REPRO_SWEEP_PROCS``
-environment variable for the benchmark harness).  Results come back in job
-order regardless of scheduling, and each job is deterministic given its
-seed, so serial and parallel execution are bit-identical.
+process for small batches, or fanned out over a spawned process pool
+(:func:`host_pool`) for figure matrices (``processes`` argument, or the
+``REPRO_SWEEP_PROCS`` environment variable for the benchmark harness).
+Results come back in job order regardless of scheduling, and each job is
+deterministic given its seed, so serial and parallel execution are
+bit-identical.
 
 ``run_sweep(jobs, lane="batched")`` (or ``REPRO_SWEEP_LANE=batched``)
 routes the whole batch through the vectorized sweep-scale lane
@@ -25,6 +26,7 @@ want anyway.
 from __future__ import annotations
 
 import dataclasses
+import multiprocessing
 import os
 from concurrent.futures import ProcessPoolExecutor
 from typing import Dict, List, Optional, Sequence
@@ -201,5 +203,16 @@ def run_sweep(
     if processes <= 1 or len(jobs) <= 1:
         return [run_job(j) for j in jobs]
     workers = min(processes, len(jobs), os.cpu_count() or 1)
-    with ProcessPoolExecutor(max_workers=workers) as pool:
+    with host_pool(workers) as pool:
         return list(pool.map(run_job, jobs))
+
+
+def host_pool(workers: int) -> ProcessPoolExecutor:
+    """A pool for host-only work (the scalar DES), started with ``spawn``.
+
+    A forked child would inherit a parent that may already hold the
+    accelerator (the batched lane's solver, a serving engine); spawned
+    workers import afresh and never touch a device."""
+    return ProcessPoolExecutor(
+        max_workers=workers, mp_context=multiprocessing.get_context("spawn")
+    )

@@ -52,6 +52,8 @@ def _scenario_md(sc: Scenario) -> List[str]:
                  else "grid (`build` + `reduce`)")
     if sc.slow:
         facts.append("slow — CI runs it in the non-gating lane")
+    if sc.device:
+        facts.append("device — runs jitted model steps on the accelerator")
     if sc.module:
         facts.append(f"legacy figure module `benchmarks/{sc.module}.py`")
     lines.append(f"*Form:* {'; '.join(facts)}.")

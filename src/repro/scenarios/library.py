@@ -724,6 +724,7 @@ register(Scenario(
     ),
     run_cell=_fig11_run_cell,
     slow=True,
+    device=True,
 ))
 
 

@@ -111,6 +111,9 @@ class Scenario:
     #: must pin lane="scalar" so REPRO_SWEEP_LANE cannot leak in.
     run_cell: Optional[Callable[..., List[Dict[str, Any]]]] = None
     slow: bool = False  # heavy scenario: CI runs it in the non-gating lane
+    #: runs jitted model steps on the accelerator, so its module must run
+    #: in the process that holds the chip, never in a pooled worker.
+    device: bool = False
 
     def __post_init__(self):
         grid_form = self.build is not None and self.reduce is not None

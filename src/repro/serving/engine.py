@@ -5,10 +5,10 @@ Architecture
 ------------
 * :class:`ServingEngine` — one model instance: continuous batching over a
   fixed slot array, real jitted prefill/decode steps, per-slot lengths.
-  The instance's *placement* decides which memory tier its weights and KV
-  live on: ``device`` (HBM — the DDR analogue) or ``host`` (pinned host
-  memory over PCIe — the CXL analogue).  Host-placed state is genuinely put
-  on the host memory space when the backend supports it.
+  The instance's *placement* decides which memory tier its weights live
+  on: ``device`` (HBM — the DDR analogue) or ``host`` (pinned host memory
+  over PCIe — the CXL analogue).  Host-placed weights are put on the
+  device's ``pinned_host`` memory space; a device without one is refused.
 
 * :class:`TieredServingCluster` — co-locates several engines on one chip's
   shared transfer path (:class:`repro.core.offload.TransferQueue`).  Every
@@ -21,9 +21,10 @@ Architecture
   contract) as on the x86 platforms and throttles each slow link's
   concurrency via tier-addressed decisions — reproducing Figure 11/12's
   DataRacing -> MIKU recovery end to end with real model math and modeled
-  PCIe timing (this container has no TPU; DESIGN.md §2).
+  PCIe timing (ARCHITECTURE.md).
 
-The wall-clock of the cluster is the simulated queue clock; model outputs
+The cluster's clock, and every ``wall_ns`` and ``tokens_per_s`` it reports,
+is the simulated transfer-queue clock, not the device's; model outputs
 (tokens) are real.
 """
 
@@ -39,7 +40,7 @@ import numpy as np
 from repro.core.controller import MikuController
 from repro.core.littles_law import OpClass
 from repro.core.offload import TransferQueue
-from repro.core.tiers import HBM_TIER, HOST_TIER, host_offload_supported
+from repro.core.tiers import HBM_TIER, HOST_TIER, require_host_offload
 from repro.models.transformer import DecodeState, ModelConfig, TransformerLM
 from repro.serving import sampler as sampler_lib
 
@@ -116,9 +117,9 @@ class ServingEngine:
             self.kv_bytes_per_token = 0
 
     def _place_state(self) -> None:
-        self._host_resident = False
-        if self.cfg.placement == "host" and host_offload_supported():
+        if self.cfg.placement == "host":
             dev = jax.devices()[0]
+            require_host_offload(dev)
             host_sh = jax.sharding.SingleDeviceSharding(
                 dev, memory_kind=HOST_TIER.memory_kind
             )
@@ -126,14 +127,13 @@ class ServingEngine:
             self._device_sh = jax.sharding.SingleDeviceSharding(
                 dev, memory_kind=HBM_TIER.memory_kind
             )
-            self._host_resident = True
 
     def step_params(self) -> Any:
-        """Working copy of the weights for one step.  Host-resident
+        """Working copy of the weights for one step.  Host-placed
         instances FETCH them device-ward — the PCIe stream the transfer
         queue charges (a TPU build would pipeline this per-layer inside the
         step; the aggregate bytes are identical)."""
-        if self._host_resident:
+        if self.cfg.placement == "host":
             return jax.device_put(self.params, self._device_sh)
         return self.params
 
@@ -349,7 +349,13 @@ class TieredServingCluster:
                 produced[name] += n
             # Advance the clock by the fast-tier step time (engines on HBM
             # run back-to-back; host engines progress via queue completions).
-            q.advance(max(fast_time, 1e3))
+            # With no HBM step this tick, jump to the next host engine's
+            # completion: a full-width host step spans ~10^5 idle 1 µs ticks.
+            step = fast_time or min(
+                (t for t in self._host_busy_until.values() if t > q.now),
+                default=q.now,
+            ) - q.now
+            q.advance(max(step, 1e3))
             self.timeline.append(
                 {"t_ns": q.now,
                  "slow_backlog": float(q.slow_backlog()),

@@ -10,9 +10,10 @@ policy outside the vectorizable registry) — ``run_sweep_batched`` must:
   scalar reruns), and record the fallback with its reason;
 * stay within the pinned fluid tolerance on co-run cells.
 
-Runs as a hypothesis property when hypothesis is installed; the container
-image does not ship it, so the same property is also exercised over a
-fixed spread of kind-sequences and rng seeds.
+Runs as a hypothesis property when hypothesis is installed, and otherwise
+over a fixed spread of kind-sequences and rng seeds.  The policy fixture is
+module-scoped: hypothesis refuses function-scoped fixtures under
+``@given``, since they would not be reset between examples.
 """
 
 from __future__ import annotations
@@ -43,7 +44,7 @@ class _FrozenPolicy:  # deliberately outside the vectorizable hierarchy
         return []
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def frozen_policy():
     POLICIES[_FrozenPolicy.name] = _FrozenPolicy
     try:

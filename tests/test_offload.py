@@ -19,10 +19,9 @@ def test_offload_roundtrip_real_arrays():
     for x, y in zip(jax.tree.leaves(tree), jax.tree.leaves(d)):
         np.testing.assert_array_equal(np.asarray(x, np.float32),
                                       np.asarray(y, np.float32))
-    if off.supported:
-        kinds = {s.memory_kind for s in
-                 [x.sharding for x in jax.tree.leaves(h)]}
-        assert kinds == {"pinned_host"}
+    kinds = {x.sharding.memory_kind for x in jax.tree.leaves(h)}
+    assert kinds == {"pinned_host"}
+    assert {x.sharding.memory_kind for x in jax.tree.leaves(d)} == {"device"}
 
 
 def test_transfer_queue_stream_duration_is_bandwidth_bound():

@@ -76,3 +76,13 @@ def test_miku_restricts_under_racing():
     )
     cl.run(20000)
     assert any(d.restricted for d in ctl.decisions)
+
+
+def test_host_only_ticks_jump_to_the_next_completion():
+    """A host step spans many 1 µs idle ticks (about 10^5 at full width);
+    with no HBM work the clock jumps to the host engine's completion, so a
+    small tick budget still finishes every request."""
+    eng = mk("h", "host", 2)
+    res = TieredServingCluster([eng]).run(60)
+    assert res["h"]["requests"] == 2
+    assert all(len(r.output) == 8 for r in eng.done)
