@@ -41,6 +41,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs.metrics import span
+
 _BISECT_ITERS = 48
 _EPS = 1e-9
 
@@ -402,13 +404,18 @@ def fused_window_solve(
     solver = fused_solver(n_outer, damp)
     big = 1e30
     f32 = lambda x: jnp.asarray(np.minimum(x, big), jnp.float32)  # noqa: E731
-    y, wq, lam = solver(
-        f32(A), f32(y_rate), f32(o_eff), f32(route), f32(route_svc),
-        f32(svc_pipe), f32(slots), f32(tor_cap[:, None]),
-        f32(irq_cap[:, None]), f32(Wq),
-    )
-    return (
-        np.asarray(y, dtype=np.float64),
-        np.asarray(wq, dtype=np.float64),
-        np.asarray(lam, dtype=np.float64),
-    )
+    with span("lane.solve.put"):
+        args = (
+            f32(A), f32(y_rate), f32(o_eff), f32(route), f32(route_svc),
+            f32(svc_pipe), f32(slots), f32(tor_cap[:, None]),
+            f32(irq_cap[:, None]), f32(Wq),
+        )
+    # The call returns once the program is queued; the fetch waits for it.
+    with span("lane.solve.call"):
+        y, wq, lam = solver(*args)
+    with span("lane.solve.fetch"):
+        return (
+            np.asarray(y, dtype=np.float64),
+            np.asarray(wq, dtype=np.float64),
+            np.asarray(lam, dtype=np.float64),
+        )

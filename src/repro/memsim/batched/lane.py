@@ -22,12 +22,14 @@ can report the split in result metadata.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from typing import List, Optional, Sequence, Tuple
 
 from repro.core.des import SimResult
 from repro.core.invariants import require, sanitize_enabled
 from repro.memsim.batched.stacking import BatchGroup, CellPlan, plan_cell
+from repro.obs.metrics import default_registry, span
 
 #: (plans aligned with the job list — None where the job fell back,
 #:  [(job_index, reason), ...] for the fallbacks)
@@ -96,16 +98,17 @@ def partition_jobs(jobs: Sequence) -> Partition:
     """Split ``jobs`` into batchable cell plans and scalar fallbacks."""
     plans: List[Optional[CellPlan]] = []
     fallbacks: List[Tuple[int, str]] = []
-    for i, job in enumerate(jobs):
-        reason = can_batch(job)
-        if reason is None:
-            try:
-                plans.append(plan_cell(job))
-                continue
-            except ValueError as ex:  # e.g. an invalid tiering region
-                reason = str(ex)
-        plans.append(None)
-        fallbacks.append((i, reason))
+    with span("lane.partition"):
+        for i, job in enumerate(jobs):
+            reason = can_batch(job)
+            if reason is None:
+                try:
+                    plans.append(plan_cell(job))
+                    continue
+                except ValueError as ex:  # e.g. an invalid tiering region
+                    reason = str(ex)
+            plans.append(None)
+            fallbacks.append((i, reason))
     return plans, fallbacks
 
 
@@ -113,6 +116,7 @@ def run_sweep_batched(
     jobs: Sequence,
     processes: Optional[int] = None,
     partition: Optional[Partition] = None,
+    profile: bool = False,
 ) -> List[SimResult]:
     """Run ``jobs`` through the batched lane, results in job order.
 
@@ -123,6 +127,8 @@ def run_sweep_batched(
     the scalar lane — through the process pool when ``processes`` says so —
     and dynamic stacking failures are appended to the partition's fallback
     list so callers holding it see the *complete* accounting.
+    ``profile=True`` marks the fallback jobs ``SimJob.profile`` (the
+    exact and fluid paths read no per-job profile).
     """
     from repro.memsim.batched import exact as exact_mod
     from repro.memsim.batched import fluid as fluid_mod
@@ -136,13 +142,19 @@ def run_sweep_batched(
     results: List[Optional[SimResult]] = [None] * len(jobs)
 
     fluid_cells: List[Tuple[int, CellPlan]] = []
-    for i, plan in enumerate(plans):
-        if plan is None:
-            continue
-        if exact_mod.exact_regime(plan) is not None:
-            results[i] = exact_mod.run_exact(plan)
-        else:
-            fluid_cells.append((i, plan))
+    n_exact = 0
+    with span("lane.exact"):
+        for i, plan in enumerate(plans):
+            if plan is None:
+                continue
+            if exact_mod.exact_regime(plan) is not None:
+                results[i] = exact_mod.run_exact(plan)
+                n_exact += 1
+            else:
+                fluid_cells.append((i, plan))
+    registry = default_registry()
+    registry.counter("lane.cells_exact").inc(n_exact)
+    registry.counter("lane.cells_fluid").inc(len(fluid_cells))
 
     # Group by window cadence (lockstep needs one shared cadence) AND by
     # ladder rung sequence (the vector ladder stacks one rung table per
@@ -161,9 +173,10 @@ def run_sweep_batched(
         # vectorized registry).  Keep the net that narrow: a failure
         # *running* the fluid engine is a bug and must surface, not
         # silently rerun scalar.
-        group = BatchGroup(cells_)
-        ladder = fluid_mod.build_ladder(group)
-        tiering = tiering_mod.build_tiering(group)
+        with span("lane.stack"):
+            group = BatchGroup(cells_)
+            ladder = fluid_mod.build_ladder(group)
+            tiering = tiering_mod.build_tiering(group)
         return group, ladder, tiering
 
     block = batch_block()
@@ -186,21 +199,23 @@ def run_sweep_batched(
                             (cell[0], f"group stacking failed: {ex}")
                         )
             for group, ladder, tiering in stacks:
-                for idx, res in zip(
-                    group.indices,
-                    fluid_mod.run_fluid(group, ladder, tiering),
-                ):
+                with span("lane.group"):
+                    group_results = fluid_mod.run_fluid(group, ladder,
+                                                        tiering)
+                for idx, res in zip(group.indices, group_results):
                     results[idx] = res
 
     # Partition-time fallbacks (plan is None); dynamic stacking fallbacks
     # were appended to ``scalar_idxs`` (and ``fallbacks``) above.
     scalar_idxs.extend(i for i, plan in enumerate(plans) if plan is None)
     if scalar_idxs:
-        for idx, res in zip(
-            scalar_idxs,
-            run_sweep([jobs[i] for i in scalar_idxs], processes,
-                      lane="scalar"),
-        ):
+        scalar_jobs = [jobs[i] for i in scalar_idxs]
+        if profile:
+            scalar_jobs = [dataclasses.replace(j, profile=True)
+                           for j in scalar_jobs]
+        with span("lane.scalar"):
+            scalar_results = run_sweep(scalar_jobs, processes, lane="scalar")
+        for idx, res in zip(scalar_idxs, scalar_results):
             results[idx] = res
     require(
         all(r is not None for r in results),

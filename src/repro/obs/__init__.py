@@ -19,14 +19,22 @@ and the tracing-off DES stays bit-identical to every pinned golden):
   gauges / histograms registered by the DES, TransferQueue, serving
   engine, ControlLoop, and sweep pool) plus a wall-clock
   :class:`~repro.obs.metrics.PhaseProfiler` for sim setup / event-loop /
-  window-pass self-profiling.
+  window-pass self-profiling, and :func:`~repro.obs.metrics.span`, the
+  program's named host phases on the ``jax.profiler`` trace's clock
+  (``repro.plan``, ``repro.lane.window``, ...), charged to the current
+  ``PhaseProfiler``.
 
 See ``docs/observability.md`` for the span schema, bucket layout, merge
 semantics, and CLI surface.
 """
 
 from repro.obs.histogram import LatencyHistogram
-from repro.obs.metrics import MetricsRegistry, PhaseProfiler, default_registry
+from repro.obs.metrics import (
+    MetricsRegistry,
+    PhaseProfiler,
+    default_registry,
+    span,
+)
 from repro.obs.trace import (
     RequestTracer,
     TraceConfig,
@@ -42,5 +50,6 @@ __all__ = [
     "TraceConfig",
     "TransferTracer",
     "default_registry",
+    "span",
     "to_chrome",
 ]

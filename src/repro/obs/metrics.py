@@ -15,13 +15,20 @@ so pool-run metrics reflect only the parent process (documented in
 for wall-clock calls by the repo lint pass, so the DES and planner call
 ``profiler.clock()`` / ``profiler.add()`` instead and stay deterministic
 when no profiler is attached.
+
+:class:`span` marks a host phase of the program: a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (a TraceMe on the
+profiler's host plane, the clock the device events are stamped on) that
+also charges its seconds to the :class:`PhaseProfiler` made current by
+:meth:`PhaseProfiler.activate`, if any.
 """
 
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
-from typing import Dict, Iterator
+from contextvars import ContextVar
+from typing import Dict, Iterator, Optional
 
 from repro.obs.histogram import LatencyHistogram
 
@@ -31,6 +38,7 @@ __all__ = [
     "MetricsRegistry",
     "PhaseProfiler",
     "default_registry",
+    "span",
 ]
 
 
@@ -144,6 +152,16 @@ class PhaseProfiler:
         self.calls[phase] = self.calls.get(phase, 0) + 1
 
     @contextmanager
+    def activate(self) -> Iterator["PhaseProfiler"]:
+        """Make this the profiler that :class:`span` charges inside the
+        ``with`` block; the one current before is restored on exit."""
+        token = _CURRENT.set(self)
+        try:
+            yield self
+        finally:
+            _CURRENT.reset(token)
+
+    @contextmanager
     def phase(self, name: str) -> Iterator[None]:
         t0 = self.clock()
         try:
@@ -158,3 +176,50 @@ class PhaseProfiler:
                 for k, v in sorted(self.seconds.items())
             }
         }
+
+
+_CURRENT: ContextVar[Optional[PhaseProfiler]] = ContextVar(
+    "repro_phase_profiler", default=None
+)
+#: ``jax.profiler.TraceAnnotation``, imported by the first span.
+_annotation = None
+#: ``name`` -> ``"repro." + name``, built once per name.
+_FULL_NAMES: Dict[str, str] = {}
+
+
+class span:
+    """A named host phase: ``with span("lane.window"): ...``.
+
+    Opens the profiler annotation ``repro.<name>`` and, when a
+    :class:`PhaseProfiler` is current (:meth:`PhaseProfiler.activate`),
+    adds the phase's seconds and one call to it under ``name``.  With no
+    trace running and no profiler current it costs one annotation's
+    construction (about a microsecond); spans mark phases, never cells.
+    """
+
+    __slots__ = ("name", "_annotation", "_prof", "_t0")
+
+    def __init__(self, name: str) -> None:
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        full = _FULL_NAMES.get(name)
+        if full is None:
+            full = _FULL_NAMES[name] = "repro." + name
+        self.name = name
+        self._annotation = _annotation(full)
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        prof = self._prof = _CURRENT.get()
+        if prof is not None:
+            self._t0 = prof.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        prof = self._prof
+        if prof is not None:
+            prof.add(self.name, prof.clock() - self._t0)
+        self._annotation.__exit__(*exc)

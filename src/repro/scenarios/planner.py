@@ -18,10 +18,12 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from repro.core.device_model import PLATFORMS, PlatformModel
 from repro.memsim.sweep import SimJob, run_sweep
+from repro.obs.metrics import PhaseProfiler, default_registry, span
 from repro.scenarios import registry
 from repro.scenarios.spec import ResultTable, Scenario
 
@@ -136,10 +138,14 @@ def run_scenario(
     --perfetto`` exports as Chrome trace-event JSON.  Traced jobs always
     run on the scalar DES.
 
-    ``profile=True`` records a wall-clock phase profile (plan / sweep /
-    reduce, plus each scalar job's setup / event-loop / window split) into
-    ``ResultTable.meta["profile"]`` and snapshots the process-wide
-    observability counters into ``meta["metrics"]``.
+    ``profile=True`` records a wall-clock phase profile into
+    ``ResultTable.meta["profile"]``: the planner's plan / sweep / reduce
+    and every :func:`~repro.obs.span` opened inside the call (the batched
+    lane's ``lane.*`` phases), plus the setup / event-loop / window split
+    of each job that runs on the scalar DES; it snapshots the process-wide
+    observability counters into ``meta["metrics"]``.  The phases are the
+    ``repro.*`` annotations a ``jax.profiler`` trace shows, whether or not
+    the call profiles.
 
     ``lane="batched"`` routes the whole grid through the vectorized sweep
     lane (:mod:`repro.memsim.batched`); jobs it cannot express fall back to
@@ -152,13 +158,10 @@ def run_scenario(
     sc = _scenario(scenario)
     values = resolve_axes(sc, overrides)
     rows: List[Dict[str, Any]] = []
+    results: list = []
     traces: Optional[List[Dict[str, Any]]] = [] if trace else None
     req_traces: Optional[List[Dict[str, Any]]] = [] if perfetto else None
-    prof = None
-    if profile:
-        from repro.obs.metrics import PhaseProfiler
-
-        prof = PhaseProfiler()
+    prof = PhaseProfiler() if profile else None
     # Resolve the effective lane up front so meta reports what actually ran
     # (lane=None defers to REPRO_SWEEP_LANE, exactly like run_sweep).
     lane = lane or default_lane()
@@ -178,26 +181,42 @@ def run_scenario(
             meta = {"lane": "scalar",
                     "note": "multi-stage (run_cell) scenario; the batched "
                             "lane applies to grid scenarios only"}
-        if prof is not None:
-            _pt = prof.clock()
-        for cell, pm in _resolved_cells(sc, values):
-            rows.extend(sc.run_cell(pm, cell, processes))
-        if prof is not None:
-            prof.add("run_cell", prof.clock() - _pt)
-    else:
-        if prof is not None:
-            _pt = prof.clock()
+    with prof.activate() if prof is not None else nullcontext():
+        if sc.run_cell is not None:
+            with span("run_cell"):
+                for cell, pm in _resolved_cells(sc, values):
+                    rows.extend(sc.run_cell(pm, cell, processes))
+        else:
+            rows, results = _run_grid(sc, values, processes, lane, meta,
+                                      traces, req_traces, profile)
+    if prof is not None:
+        meta["profile"] = prof.snapshot()
+        meta["profile"]["jobs"] = [r.profile for r in results if r.profile]
+        meta["metrics"] = default_registry().snapshot()
+    return ResultTable(scenario=sc.name, rows=rows, params=values,
+                       traces=traces, meta=meta,
+                       request_traces=req_traces)
+
+
+def _run_grid(sc, values, processes, lane, meta, traces, req_traces,
+              profile):
+    """Plan, sweep and reduce a grid scenario: ``(rows, results)``.
+
+    The batched lane's results land in ``meta``'s job split; ``traces``
+    and ``req_traces`` (when lists) collect each cell's window records and
+    request-trace payloads."""
+    with span("plan"):
         planned = [
             (cell, pm, sc.build(pm, cell))
             for cell, pm in _resolved_cells(sc, values)
         ]
-        if trace:
+        if traces is not None:
             planned = [
                 (cell, pm,
                  [dataclasses.replace(j, record_windows=True) for j in jobs])
                 for cell, pm, jobs in planned
             ]
-        if perfetto:
+        if req_traces is not None:
             # Every 16th ToR admission: dense enough that even a short CI
             # cell lands spans, sparse enough to keep the export small.
             planned = [
@@ -205,21 +224,22 @@ def run_scenario(
                  [dataclasses.replace(j, trace=16) for j in jobs])
                 for cell, pm, jobs in planned
             ]
-        if prof is not None:
+        if profile and lane != "batched":
+            # Only the scalar DES reads SimJob.profile; the batched lane
+            # marks the jobs it falls back itself.
             planned = [
                 (cell, pm,
                  [dataclasses.replace(j, profile=True) for j in jobs])
                 for cell, pm, jobs in planned
             ]
-            prof.add("plan", prof.clock() - _pt)
-            _pt = prof.clock()
+    with span("sweep"):
         all_jobs: List[SimJob] = [j for _, _, jobs in planned for j in jobs]
         if lane == "batched":
             from repro.memsim.batched import partition_jobs, run_sweep_batched
 
             partition = partition_jobs(all_jobs)
             results = run_sweep_batched(all_jobs, processes,
-                                        partition=partition)
+                                        partition=partition, profile=profile)
             # Account fallbacks *after* the run: run_sweep_batched appends
             # dynamic stacking failures to the partition's fallback list.
             _, fallbacks = partition
@@ -234,9 +254,8 @@ def run_scenario(
             )
         else:
             results = run_sweep(all_jobs, processes, lane=lane)
-        if prof is not None:
-            prof.add("sweep", prof.clock() - _pt)
-            _pt = prof.clock()
+    rows: List[Dict[str, Any]] = []
+    with span("reduce"):
         i = 0
         for cell, pm, jobs in planned:
             chunk = results[i: i + len(jobs)]
@@ -268,19 +287,7 @@ def run_scenario(
                         for j, (job, res) in enumerate(zip(jobs, chunk))
                     ],
                 })
-        if prof is not None:
-            prof.add("reduce", prof.clock() - _pt)
-    if prof is not None:
-        from repro.obs.metrics import default_registry
-
-        meta["profile"] = prof.snapshot()
-        meta["profile"]["jobs"] = [
-            r.profile for r in results if getattr(r, "profile", None)
-        ] if sc.run_cell is None else []
-        meta["metrics"] = default_registry().snapshot()
-    return ResultTable(scenario=sc.name, rows=rows, params=values,
-                       traces=traces, meta=meta,
-                       request_traces=req_traces)
+    return rows, results
 
 
 def parse_set_args(

@@ -24,6 +24,10 @@ Six contracts:
 6. **Lane parity** — the batched exact lane's histogram equals the scalar
    DES's exactly; the fluid lane's analytic synthesis lands within the
    documented tolerance; traced jobs fall back to the scalar DES.
+7. **Program spans** — ``span`` charges only the current
+   ``PhaseProfiler``; a profiled batched grid reports the lane's phases
+   (one ``lane.window`` per group and window) with rows unchanged, and
+   only jobs that run on the scalar DES carry per-job profiles.
 """
 
 import dataclasses
@@ -46,6 +50,7 @@ from repro.obs import (
     TraceConfig,
     TransferTracer,
     default_registry,
+    span,
     to_chrome,
 )
 from repro.obs.histogram import bucket_bounds, bucket_index, merge_all
@@ -465,6 +470,90 @@ def test_phase_profiler():
     snap = prof.snapshot()
     assert snap["phases"]["work"]["calls"] == 2
     assert snap["phases"]["work"]["seconds"] >= 0.0
+
+
+def test_span_is_a_noop_without_a_current_profiler():
+    prof = PhaseProfiler()
+    with span("lane.window"):
+        pass
+    assert prof.snapshot() == {"phases": {}}
+    with prof.activate():
+        pass
+    with span("lane.window"):  # the activation has ended
+        pass
+    assert prof.snapshot() == {"phases": {}}
+
+
+def test_nested_spans_charge_the_current_profiler():
+    prof = PhaseProfiler()
+    with prof.activate():
+        for _ in range(3):
+            with span("outer"):
+                with span("inner"):
+                    math.sqrt(2.0)
+    phases = prof.snapshot()["phases"]
+    assert phases["outer"]["calls"] == 3
+    assert phases["inner"]["calls"] == 3
+    assert prof.seconds["outer"] >= prof.seconds["inner"] > 0.0
+
+
+#: A small batched co-run grid: 2 ops x 2 MIKU settings, so two fluid
+#: groups (MIKU on, MIKU off) of 10 windows each.
+_LANE_GRID = {"platform": ("A",), "threads": (4,), "mlp": (64,)}
+
+
+@pytest.fixture(scope="module")
+def lane_tables():
+    from repro.scenarios import run_scenario
+
+    def run(profile):
+        return run_scenario("corun_sweep_1k", _LANE_GRID, lane="batched",
+                            profile=profile)
+
+    return run(True), run(False)
+
+
+def test_batched_lane_reports_its_phases(lane_tables):
+    profiled, _ = lane_tables
+    phases = profiled.meta["profile"]["phases"]
+    for name in ("plan", "sweep", "reduce", "lane.partition", "lane.stack",
+                 "lane.ladder", "lane.apply", "lane.window", "lane.solve"):
+        assert phases[name]["calls"] > 0, name
+    groups = phases["lane.group"]["calls"]
+    assert groups == 2
+    assert phases["lane.window"]["calls"] == groups * 10
+    # Only the MIKU group runs the ladder; every window applies.
+    assert phases["lane.ladder"]["calls"] == 10
+    assert phases["lane.apply"]["calls"] == groups * 10
+    assert phases["lane.window"]["seconds"] <= phases["sweep"]["seconds"]
+    # The fluid and exact paths read no per-job profile.
+    assert profiled.meta["profile"]["jobs"] == []
+    counters = profiled.meta["metrics"]["counters"]
+    assert counters["lane.windows"] >= groups * 10
+    assert counters["lane.cells_fluid"] >= len(profiled.rows)
+
+
+def test_batched_lane_rows_are_identical_when_profiled(lane_tables):
+    profiled, plain = lane_tables
+    assert len(profiled.rows) == 4
+    assert profiled.rows == plain.rows
+    assert "profile" not in plain.meta
+
+
+def test_fallback_jobs_keep_their_profiles():
+    from repro.scenarios import run_scenario
+
+    table = run_scenario(
+        "fig4_latency",
+        {"platform": "A", "tier": ("cxl",), "threads": (4,)},
+        lane="batched", perfetto=True, profile=True,
+    )
+    n = table.meta["scalar_fallback_jobs"]
+    assert n > 0 and table.meta["fallback_reasons"] == ["trace"]
+    jobs = table.meta["profile"]["jobs"]
+    assert len(jobs) == n
+    assert all("event_loop" in j["phases"] for j in jobs)
+    assert table.meta["profile"]["phases"]["lane.scalar"]["calls"] == 1
 
 
 def test_tracer_config_validation():
