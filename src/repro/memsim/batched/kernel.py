@@ -29,9 +29,14 @@ fluid engine hands the *entire* per-window wait-relaxation loop (station
 scaling, global-λ Pallas bisection, queue-builder population
 accounting, Little's-law wait update — everything between routing setup
 and the control-window fire) to one jit-compiled function, so a window
-costs one device dispatch instead of ``n_outer`` python iterations of
+costs one solver dispatch instead of ``n_outer`` python iterations of
 einsums.  That is what scales 1k+-cell grids: the python overhead per
-window becomes O(1) in cell count.
+window becomes O(1) in cell count.  The window's ten input arrays travel
+as one packed ``(C, 3W + 3WS + 2S + 2)`` float32 buffer
+(:func:`pack_window`), unpacked on the device by static slices in a
+program of its own, and ``y``, ``Wq`` and ``λ`` return as one
+``(C, W + S + 1)`` array: one host-to-device and one device-to-host copy
+per window, since each transfer costs far more than its bytes.
 """
 
 from __future__ import annotations
@@ -41,10 +46,12 @@ from typing import Optional
 
 import numpy as np
 
-from repro.obs.metrics import span
+from repro.obs.metrics import default_registry, span
 
 _BISECT_ITERS = 48
 _EPS = 1e-9
+#: f32-safe stand-in for ``+inf`` in the device solvers' inputs.
+_BIG = 1e30
 
 
 def backend() -> str:
@@ -203,9 +210,8 @@ def _global_lambda_pallas(A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap):
 
     if _pallas_solver is None:
         _pallas_solver = _build_pallas_solver(_default_interpret())
-    big = 1e30  # f32-safe stand-in for +inf inputs
-    f32 = lambda x: jnp.asarray(np.minimum(x, big), jnp.float32)  # noqa: E731
-    hi0 = (np.minimum(cap, big) / np.maximum(A, 1e-12)).max(axis=1) + 1e-6
+    f32 = lambda x: jnp.asarray(np.minimum(x, _BIG), jnp.float32)  # noqa: E731
+    hi0 = (np.minimum(cap, _BIG) / np.maximum(A, 1e-12)).max(axis=1) + 1e-6
     lam = _pallas_solver(
         f32(A), f32(cap), f32(y_sta), f32(o_eff), f32(R_tor),
         f32(tor_cap[:, None]), f32(irq_cap[:, None]), f32(hi0[:, None]),
@@ -242,6 +248,35 @@ def global_lambda(
 _fused_solvers: dict = {}
 
 
+def _window_shapes(W: int, S: int) -> tuple:
+    """Per-cell shapes of the window solve's ten inputs, in packed order:
+    ``A``, ``y_rate``, ``o_eff`` (W); ``route``, ``route_svc``,
+    ``svc_pipe`` (W, S); ``slots`` (S); ``tor_cap``, ``irq_cap`` (1);
+    ``Wq`` (S)."""
+    return ((W,),) * 3 + ((W, S),) * 3 + ((S,), (1,), (1,), (S,))
+
+
+def pack_window(*arrays) -> np.ndarray:
+    """The ten inputs of a window solve as one contiguous ``(C, K)``
+    float32 buffer, ``K = 3W + 3WS + 2S + 2``: each array flattened per
+    cell, in :func:`_window_shapes` order, clipped to ``1e30``."""
+    C = arrays[0].shape[0]
+    cols = np.concatenate([np.reshape(x, (C, -1)) for x in arrays], axis=1)
+    return np.minimum(cols, _BIG).astype(np.float32)
+
+
+def unpack_window(packed, W: int, S: int) -> tuple:
+    """The ten arrays of :func:`pack_window`'s buffer, by static slices
+    (numpy or jax arrays alike); ``tor_cap``/``irq_cap`` as ``(C, 1)``."""
+    C = packed.shape[0]
+    out, at = [], 0
+    for shape in _window_shapes(W, S):
+        n = int(np.prod(shape))
+        out.append(packed[:, at:at + n].reshape((C,) + shape))
+        at += n
+    return tuple(out)
+
+
 def fused_solver_args(C: int, W: int, S: int, sharding=None) -> tuple:
     """Shape stand-ins for the fused solver's arguments at ``(C, W, S)``
     cells × workloads × stations, for lowering it without data."""
@@ -254,6 +289,24 @@ def fused_solver_args(C: int, W: int, S: int, sharding=None) -> tuple:
     cw, cws = f32(C, W), f32(C, W, S)
     return (cw, cw, cw, cws, cws, cws, f32(C, S), f32(C, 1), f32(C, 1),
             f32(C, S))
+
+
+_unpacker = None
+
+
+def _unpack_on_device(packed, W: int, S: int) -> tuple:
+    """:func:`unpack_window` as its own jitted program (``jit_unpack_window``).
+
+    Kept apart from the solver on purpose: slicing inside the solver's
+    program changes how XLA lays out the relaxation's inputs on the TPU,
+    and with them the float32 rounding of a few threshold cells; ten
+    separate arrays leave the solver's program as it was."""
+    global _unpacker
+    if _unpacker is None:
+        import jax
+
+        _unpacker = jax.jit(unpack_window, static_argnums=(1, 2))
+    return _unpacker(packed, W, S)
 
 
 def fused_solver(n_outer: int, damp: float):
@@ -270,21 +323,40 @@ def fused_solver(n_outer: int, damp: float):
 def build_fused_solver(n_outer: int, damp: float, interpret: bool):
     """Compile the whole wait-relaxation loop as one jit function.
 
+    ``solve`` takes the ten float32 arrays (:func:`fused_solver_args`),
+    runs :func:`build_relaxation`'s loop and returns ``y``, ``Wq`` and
+    ``λ`` concatenated as one ``(C, W + S + 1)`` array, so a window's
+    results come back in one copy.  The jitted function keeps the name
+    ``solve``: its program is ``jit_solve`` on a device trace."""
+    import jax
+    import jax.numpy as jnp
+
+    relax = build_relaxation(n_outer, damp, interpret)
+
+    def solve(*arrays):
+        y, Wq, lam = relax(*arrays)
+        return jnp.concatenate([y, Wq, lam[:, None]], axis=1)
+
+    return jax.jit(solve)
+
+
+def build_relaxation(n_outer: int, damp: float, interpret: bool):
+    """The wait-relaxation loop on the ten unpacked float32 arrays.
+
     The outer loop (``n_outer`` damped iterations), the station bisection,
-    and the global-λ Pallas bisection all run inside a single ``jax.jit``
-    trace, so the fluid engine pays one dispatch per window regardless of
-    cell count.  f32 throughout with ``1e30`` standing in for ``+inf``.
-    ``interpret`` comes from the caller (:func:`interpret_mode` of the
-    platform that will run it)."""
+    and the global-λ Pallas bisection run inside whatever ``jax.jit``
+    traces it (:func:`build_fused_solver`), so the fluid engine pays one
+    solver dispatch per window regardless of cell count.  f32 throughout with
+    ``1e30`` standing in for ``+inf``.  ``interpret`` comes from the caller
+    (:func:`interpret_mode` of the platform that will run it)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     glam_kernel = _glam_kernel(jax, jnp)
-    big = 1e30
 
     def glam(A, cap, y_sta, o_eff, r_tor, tor, irq):
-        hi0 = (jnp.minimum(cap, big)
+        hi0 = (jnp.minimum(cap, _BIG)
                / jnp.maximum(A, 1e-12)).max(axis=1, keepdims=True) + 1e-6
         return pl.pallas_call(
             glam_kernel,
@@ -310,10 +382,9 @@ def build_fused_solver(n_outer: int, damp: float, interpret: bool):
             return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
 
         lo, _ = jax.lax.fori_loop(0, _BISECT_ITERS, body, (lo, hi))
-        return jnp.where(feasible_at_cap, big, lo)
+        return jnp.where(feasible_at_cap, _BIG, lo)
 
-    @jax.jit
-    def solve(A, y_rate, o_eff, route, route_svc, svc_pipe, slots, tor,
+    def relax(A, y_rate, o_eff, route, route_svc, svc_pipe, slots, tor,
               irq, Wq0):
         # Mirrors the numpy relaxation in fluid.run_fluid line for line;
         # tor/irq arrive as (C, 1) columns for in-kernel broadcasting.
@@ -327,10 +398,10 @@ def build_fused_solver(n_outer: int, damp: float, interpret: bool):
             cap = jnp.minimum(y_rate, o_eff / jnp.maximum(R_tor, 1e-9))
             cap = jnp.where(A > 0, cap, 0.0)
             lam_s = station_lams(A, cap, route_svc, slots)
-            lam_min = jnp.where(used, lam_s[:, None, :], big).min(axis=2)
-            y_sta = jnp.minimum(lam_min, big) * jnp.maximum(A, 0.0)
+            lam_min = jnp.where(used, lam_s[:, None, :], _BIG).min(axis=2)
+            y_sta = jnp.minimum(lam_min, _BIG) * jnp.maximum(A, 0.0)
             lam = glam(A, cap, y_sta, o_eff, R_tor, tor, irq)  # (C, 1)
-            lam_b = jnp.minimum(lam, big)
+            lam_b = jnp.minimum(lam, _BIG)
             y_free = jnp.minimum(lam_b * A, cap)
             y = jnp.minimum(y_free, y_sta)
             qb = (y_sta <= lam_b * A * (1.0 + 1e-9)) & (
@@ -374,7 +445,7 @@ def build_fused_solver(n_outer: int, damp: float, interpret: bool):
         )
         return y, Wq, lam[:, 0]
 
-    return solve
+    return relax
 
 
 def fused_window_solve(
@@ -391,31 +462,37 @@ def fused_window_solve(
     n_outer: int,
     damp: float,
 ) -> tuple:
-    """One jit dispatch for a window's full wait-relaxation loop.
+    """One solver dispatch for a window's full wait-relaxation loop.
 
-    Numpy in / numpy out: arrays go to f32 on device (``1e30`` standing in
-    for ``+inf`` rate caps) and come back float64.  Returns ``(y, Wq, lam)``
-    with ``lam`` the last iteration's global λ — ``+inf`` where the ToR
-    never fills, so ``np.isfinite(lam)`` stays the coupling test.  Raises
-    on any jax failure.
+    Numpy in / numpy out.  The ten arrays go to the device as one packed
+    ``(C, 3W + 3WS + 2S + 2)`` float32 buffer (:func:`pack_window`, ``1e30``
+    standing in for ``+inf`` rate caps), which a small program unpacks
+    there (:func:`_unpack_on_device`), and ``y``, ``Wq`` and ``λ`` come
+    back as one ``(C, W + S + 1)`` array, split here and cast to float64:
+    one copy each way, counted by ``lane.solve_transfers``.  Returns
+    ``(y, Wq, lam)`` with ``lam`` the last iteration's global λ — ``+inf``
+    where the ToR never fills, so ``np.isfinite(lam)`` stays the coupling
+    test.  Raises on any jax failure.
     """
-    import jax.numpy as jnp
+    import jax
 
     solver = fused_solver(n_outer, damp)
-    big = 1e30
-    f32 = lambda x: jnp.asarray(np.minimum(x, big), jnp.float32)  # noqa: E731
+    W, S = A.shape[1], slots.shape[1]
+    transfers = default_registry().counter("lane.solve_transfers")
     with span("lane.solve.put"):
-        args = (
-            f32(A), f32(y_rate), f32(o_eff), f32(route), f32(route_svc),
-            f32(svc_pipe), f32(slots), f32(tor_cap[:, None]),
-            f32(irq_cap[:, None]), f32(Wq),
-        )
-    # The call returns once the program is queued; the fetch waits for it.
+        packed = jax.device_put(pack_window(
+            A, y_rate, o_eff, route, route_svc, svc_pipe, slots, tor_cap,
+            irq_cap, Wq,
+        ))
+        transfers.inc()
+    # The calls return once the programs are queued; the fetch waits.
     with span("lane.solve.call"):
-        y, wq, lam = solver(*args)
+        out = solver(*_unpack_on_device(packed, W, S))
     with span("lane.solve.fetch"):
+        out = np.asarray(out)
+        transfers.inc()
         return (
-            np.asarray(y, dtype=np.float64),
-            np.asarray(wq, dtype=np.float64),
-            np.asarray(lam, dtype=np.float64),
+            out[:, :W].astype(np.float64),
+            out[:, W:W + S].astype(np.float64),
+            out[:, W + S].astype(np.float64),
         )

@@ -494,3 +494,84 @@ def test_pallas_backend_matches_numpy():
     assert (np.isfinite(lam_pl) == finite).all()
     # f32 kernel vs f64 numpy: parity to f32 tolerance.
     assert lam_pl[finite] == pytest.approx(lam_np[finite], rel=2e-3)
+
+
+def _window_inputs(C, W, S, seed):
+    """The ten inputs of one window solve: some cells uncapped (``+inf``
+    rate caps), some whose ToR never fills (infinite λ)."""
+    rng = np.random.default_rng(seed)
+    A = rng.integers(0, 17, (C, W)).astype(float)
+    y_rate = np.where(rng.random((C, W)) < 0.5, np.inf,
+                      rng.uniform(0.01, 2.0, (C, W)))
+    route = rng.dirichlet(np.ones(S), (C, W))
+    svc = rng.uniform(20.0, 400.0, (C, W, S))
+    o_eff = A * rng.uniform(8.0, 64.0, (C, W))
+    tor = np.where(np.arange(C) % 3 == 0, 1e9, rng.uniform(32.0, 256.0, C))
+    irq = np.full(C, 64.0)
+    slots = rng.integers(1, 8, (C, S)).astype(float)
+    Wq = rng.uniform(0.0, 50.0, (C, S))
+    return (A, y_rate, o_eff, route, route * svc, svc + 5.0, slots, tor,
+            irq, Wq)
+
+
+def _f32(arrays):
+    """Each input as the device sees it: clipped to 1e30, float32, with
+    the ToR and IRQ capacities as (C, 1) columns."""
+    out = [np.minimum(x, 1e30).astype(np.float32) for x in arrays]
+    out[7], out[8] = out[7][:, None], out[8][:, None]
+    return out
+
+
+def test_window_pack_round_trip_is_exact():
+    from repro.memsim.batched import kernel
+
+    C, W, S = 6, 3, 4
+    arrays = _window_inputs(C, W, S, 1)
+    packed = kernel.pack_window(*arrays)
+    assert packed.dtype == np.float32 and packed.flags.c_contiguous
+    assert packed.shape == (C, 3 * W + 3 * W * S + 2 * S + 2)
+    back = kernel.unpack_window(packed, W, S)
+    assert len(back) == len(arrays) == 10
+    for got, want in zip(back, _f32(arrays)):
+        assert got.shape == want.shape
+        assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("C,W,S", [(6, 3, 4), (512, 2, 3)])
+def test_packed_window_solve_is_bit_identical(C, W, S):
+    """One packed copy each way gives exactly what the relaxation gives on
+    the ten float32 arrays sent one by one."""
+    jax = pytest.importorskip("jax")
+    from repro.memsim.batched import fluid, kernel
+
+    arrays = _window_inputs(C, W, S, 7)
+    relax = jax.jit(kernel.build_relaxation(fluid._N_OUTER, fluid._DAMP,
+                                            interpret=True))
+    want = [np.asarray(x, dtype=np.float64) for x in relax(*_f32(arrays))]
+    got = kernel.fused_window_solve(*arrays, fluid._N_OUTER, fluid._DAMP)
+    assert np.isinf(got[2]).any() and np.isfinite(got[2]).any()
+    for g, w in zip(got, want):
+        assert g.dtype == np.float64 and g.shape == w.shape
+        assert np.array_equal(g, w, equal_nan=True)
+
+
+def test_window_solve_makes_one_copy_each_way(caplog):
+    """Two host-device copies per solve, and no device program besides the
+    unpacking and the solver (no per-array float32 conversions)."""
+    jax = pytest.importorskip("jax")
+    import logging
+
+    from repro.memsim.batched import fluid, kernel
+    from repro.obs.metrics import default_registry
+
+    transfers = default_registry().counter("lane.solve_transfers")
+    arrays = _window_inputs(11, 2, 3, 3)  # a shape no other test compiles
+    before = transfers.value
+    with caplog.at_level(logging.WARNING), jax.log_compiles(True):
+        kernel.fused_window_solve(*arrays, fluid._N_OUTER, fluid._DAMP)
+    assert transfers.value - before == 2
+    compiled = [r.getMessage().split()[1] for r in caplog.records
+                if r.getMessage().startswith("Compiling ")]
+    assert sorted(compiled) == ["jit(solve)", "jit(unpack_window)"]
+    kernel.fused_window_solve(*arrays, fluid._N_OUTER, fluid._DAMP)
+    assert transfers.value - before == 4
