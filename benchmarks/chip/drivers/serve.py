@@ -129,15 +129,23 @@ def program_params(cfg: Dict[str, Any], seed: int, model):
     return jax.block_until_ready(params)
 
 
+#: Seeds the one pairing of prompt and output lengths in a block: the same
+#: for every run and ``--seed``, so every run serves the same sizes.
+PAIRING_SEED = 15
+
+
 def request_sizes(tr: Dict[str, Any]) -> List[tuple]:
-    """The block of (prompt, output) sizes every client walks."""
+    """The block of (prompt, output) sizes every client walks.  The
+    outputs are paired with the prompts in an order drawn once from
+    ``PAIRING_SEED``, so the two lengths are independent."""
     prompts = [p for p, n in zip(tr["prompt_lengths"], tr["prompt_counts"])
                for _ in range(n)]
     n = len(prompts)
     lo, hi = math.log(tr["output_min"]), math.log(tr["output_max"])
     outs = [int(round(math.exp(lo + (hi - lo) * (i + 0.5) / n)))
             for i in range(n)]
-    return list(zip(prompts, outs))
+    order = np.random.default_rng(PAIRING_SEED).permutation(n)
+    return [(p, outs[i]) for p, i in zip(prompts, order)]
 
 
 def _controller(host_engine, stream_chunks: int):
@@ -238,7 +246,9 @@ def setup(cell, seed: int) -> State:
 
     cfg, tr = cell.config, cell.traffic
     mcfg = model_config(cfg)
+    t0 = time.perf_counter()
     params = program_params(cfg, seed, TransformerLM(mcfg))
+    t_weights = time.perf_counter()
     engines = {}
     for e in tr["engines"]:
         engines[e["name"]] = ServingEngine(
@@ -273,18 +283,23 @@ def setup(cell, seed: int) -> State:
     # programs that take them then compile anew (host-placed engines).
     lengths = tr["prompt_lengths"]
     per_engine: Dict[str, int] = {}
-    now = time.perf_counter()
+    now = t_engines = time.perf_counter()
     for c in clients:
         k = per_engine.get(c.engine.cfg.name, 0)
         per_engine[c.engine.cfg.name] = k + 1
         _send(state, c, now, prompt_len=lengths[k % len(lengths)],
               n_out=2 if k == 0 else None)
+    ticks = 0
     while not all(v["decode"] >= 2 and v["admit_after_decode"]
                   for v in state.steps.values()):
         cluster.run(max_ticks=1)
         _harvest(state, time.perf_counter(), resend=True)
+        ticks += 1
     for v in state.record.values():
         v.clear()
+    print(f"[setup] weights {t_weights - t0:.3f} s, engines and clients "
+          f"{t_engines - t_weights:.3f} s, warm-up {ticks} ticks "
+          f"{time.perf_counter() - t_engines:.3f} s", file=sys.stderr)
     return state
 
 
@@ -322,8 +337,11 @@ def _traced(state: State, tokens: Dict[str, int], host: List[str],
 
 def window(state: State, seconds: float, capture) -> Dict[str, Any]:
     """Tick for ``seconds``; a ``capture`` traces the first
-    ``trace_seconds`` of it, and the counts of that part are kept apart
-    (stopping the profiler holds the loop while it writes the trace)."""
+    ``trace_seconds`` of it, and the counts of that part are kept apart.
+    Stopping the profiler holds the loop while it writes the trace (tens
+    of seconds); a traced window serves for ``seconds`` besides that hold,
+    so that it finishes as many requests as an untraced one and its check
+    compares as much."""
     cluster = state.cluster
     n_before = len(state.reqs)
     tokens = {name: 0 for name in state.engines}
@@ -333,6 +351,7 @@ def window(state: State, seconds: float, capture) -> Dict[str, Any]:
                   if e.cfg.placement == "host")
     if capture is not None:
         capture.start()
+    held = 0.0
     t0 = time.perf_counter()
     while True:
         with tracing.span("tick"):
@@ -342,8 +361,9 @@ def window(state: State, seconds: float, capture) -> Dict[str, Any]:
             tokens[name] += n
         if capture is not None and traced is None and now - t0 >= trace_s:
             capture.stop()
+            held = time.perf_counter() - now
             traced = _traced(state, tokens, host, now - t0)
-        if now - t0 >= seconds:
+        if now - t0 - held >= seconds:
             break
     t1 = time.perf_counter()
     if capture is not None and traced is None:
@@ -446,6 +466,7 @@ def readings(state: State, run, control: bool = False) -> Dict[str, Any]:
           f"use before the reference: {in_use}", file=sys.stderr)
     ref = qwen2.served_logits(state.cfg, state.seed, seqs,
                               max_len=state.traffic["max_len"],
+                              rows=state.traffic["output_max"],
                               control=control)
 
     def widest(xs):

@@ -154,8 +154,8 @@ def test_lane_control_fails(monkeypatch):
 
 # -- serving -----------------------------------------------------------------
 #
-# No cell serves yet (see PERF.md): the serving driver is checked here on a
-# cell of the tests' own, a closed loop at small sizes.
+# The serving driver is checked here on a cell of the tests' own: the
+# serving cells' closed loop at small sizes.
 
 
 def serve_cell(engines):
