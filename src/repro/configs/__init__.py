@@ -49,6 +49,7 @@ ARCH_IDS: List[str] = [
     "mamba2-2.7b",
     # the paper's own LLM-serving case-study model (§6, LLaMA 3.1 8B class):
     "llama31-8b",
+    "granite-4.0-h-small",
 ]
 
 _MODULES = {
@@ -63,6 +64,7 @@ _MODULES = {
     "llama4-maverick-400b-a17b": "llama4_maverick",
     "mamba2-2.7b": "mamba2_2p7b",
     "llama31-8b": "llama31_8b",
+    "granite-4.0-h-small": "granite_4h_small",
 }
 
 

@@ -301,3 +301,39 @@ def attend_cached(
     out = _grouped_values(probs, v)  # [B,1,Hq,Dh]
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, cache
+
+
+def attend_cached_stacked(
+    params: Params,
+    x: jax.Array,
+    cache: Dict[str, jax.Array],
+    layer: jax.Array,
+    length: jax.Array,
+    *,
+    rope_theta: Optional[float],
+    query_scale: Optional[float] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token decode against the K/V of every attention layer of a
+    stack, ``cache`` k/v: [L_attn, B, S_max, Hkv, Dh].  The new row of each
+    slot is written in place at ``[layer, slot, length[slot]]``, and the
+    token attends to the positions up to it.  Returns ([B, 1, D], cache)."""
+    b = x.shape[0]
+    positions = length[:, None]  # [B,1]
+    q, k_new, v_new = project_qkv(params, x, positions, rope_theta=rope_theta)
+    slots = jnp.arange(b)
+    cache = {
+        "k": cache["k"].at[layer, slots, length].set(
+            k_new[:, 0].astype(cache["k"].dtype)),
+        "v": cache["v"].at[layer, slots, length].set(
+            v_new[:, 0].astype(cache["v"].dtype)),
+    }
+    k = jax.lax.dynamic_index_in_dim(cache["k"], layer, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(cache["v"], layer, keepdims=False)
+    dh = q.shape[-1]
+    scale = query_scale if query_scale is not None else dh**-0.5
+    scores = _grouped_scores(q * scale, k)  # [B,Hq,1,S_max]
+    valid = jnp.arange(k.shape[1])[None, :] <= positions  # [B,S_max]
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
+    out = _grouped_values(probs, v)  # [B,1,Hq,Dh]
+    return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), cache

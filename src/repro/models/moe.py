@@ -6,6 +6,11 @@ sort/gather/scatter pipeline — *not* one-hot dispatch einsums, whose
 [tokens x experts x capacity] contractions would add O(T^2) FLOPs at 128
 experts and drown the roofline's useful-compute ratio.
 
+:func:`held_moe_apply` is the expert layer of one expert-parallel share
+(granite-4.0-h): it routes over every expert, holds the weights of a
+contiguous range of them, and computes their part of the result without
+dropping a token.
+
 Expert weights are stacked [E, ...] and logically sharded over the
 ``experts`` axis (expert parallelism); the gather/scatter pair is what GSPMD
 turns into the all-to-all (baseline) — the perf pass replaces it with an
@@ -279,3 +284,86 @@ def _moe_apply_local(
             b, s, d)
 
     return out, aux_loss
+
+
+# ---------------------------------------------------------------------------
+# One expert-parallel share: routed over all experts, computed for the held
+# ---------------------------------------------------------------------------
+
+
+def held_moe_init(
+    key,
+    d_model: int,
+    d_ff: int,
+    n_experts: int,
+    held: Tuple[int, int],
+    dtype,
+    *,
+    stacked: Optional[int] = None,
+    shared_expert_ff: int = 0,
+) -> Tuple[Params, Axes]:
+    """A router over all ``n_experts`` and the weights of experts
+    ``[held[0], held[1])`` only (plus the shared MLP, which every share
+    holds whole).  The gate and up weights are laid out [E, F, D], with
+    the contracted width minor, as the TPU's long-prompt products want it
+    (a [E, D, F] stack is copied whole into that layout for them)."""
+    lo, hi = held
+    kr, ke = jax.random.split(key)
+    params, axes = moe_init(ke, d_model, d_ff, hi - lo, dtype,
+                            stacked=stacked,
+                            shared_expert_ff=shared_expert_ff)
+    lead = (stacked,) if stacked else ()
+    lead_ax = ("layers",) if stacked else ()
+    params["router"] = dense_init(kr, d_model, lead + (d_model, n_experts),
+                                  dtype)
+    for name in ("w_gate", "w_up"):
+        params[name] = jnp.swapaxes(params[name], -2, -1)
+        axes[name] = lead_ax + ("experts", "ffn", "embed")
+    return params, axes
+
+
+def held_moe_apply(
+    params: Params,
+    x: jax.Array,
+    *,
+    top_k: int,
+    held: Tuple[int, int],
+    activation: str = "silu",
+) -> Tuple[jax.Array, jax.Array]:
+    """x: [B, S, D] -> (this share's output [B, S, D], held experts that
+    at least one token was routed to).
+
+    Granite's router: float32 logits over every expert, the ``top_k``
+    largest, and a softmax over those ``top_k`` logits alone.  Each held
+    expert runs on every token, weighted by that token's gate for it (0
+    where the token was routed elsewhere), so no token is dropped and a
+    token routed to experts held on other chips adds nothing here.  The
+    gate and up projections of all held experts are one matrix product,
+    and so are the down projections and their weighted sum."""
+    b, s, d = x.shape
+    lo, hi = held
+    xf = x.reshape(b * s, d)
+    logits = jnp.einsum("td,de->te", xf, params["router"],
+                        preferred_element_type=jnp.float32)
+    top_vals, top_idx = jax.lax.top_k(logits, top_k)
+    gates = jax.nn.softmax(top_vals, axis=-1)  # [T, k]
+    ids = lo + jnp.arange(hi - lo)
+    weight = jnp.sum(jnp.where(top_idx[:, :, None] == ids, gates[:, :, None],
+                               0.0), axis=1)  # [T, held]
+    g = jnp.einsum("td,efd->etf", xf, params["w_gate"])
+    u = jnp.einsum("td,efd->etf", xf, params["w_up"])
+    act = jax.nn.silu(g) if activation == "silu" else jax.nn.gelu(
+        g, approximate=True)
+    act = act * u * weight.T[:, :, None].astype(x.dtype)
+    out = jnp.einsum("etf,efd->td", act, params["w_down"],
+                     preferred_element_type=jnp.float32)
+    if "shared" in params:
+        sh = params["shared"]
+        g2 = jnp.einsum("td,df->tf", xf, sh["w_gate"])
+        u2 = jnp.einsum("td,df->tf", xf, sh["w_up"])
+        a2 = jax.nn.silu(g2) if activation == "silu" else jax.nn.gelu(
+            g2, approximate=True)
+        out = out + jnp.einsum("tf,fd->td", a2 * u2, sh["w_down"],
+                               preferred_element_type=jnp.float32)
+    touched = jnp.sum(jnp.any(weight > 0.0, axis=0).astype(jnp.int32))
+    return out.astype(x.dtype).reshape(b, s, d), touched

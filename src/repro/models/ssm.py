@@ -6,9 +6,12 @@ across chunks a small [H, P, N] state is carried by a scan.  This is both
 the jnp baseline (lowering-friendly: one lax.scan over chunks nested inside
 the layer scan) and the oracle for the Pallas ``ssd_scan`` kernel.
 
-Decode is the pure recurrence: O(1) state per token — which is exactly why
-attention-KV tiering is inapplicable to this family (DESIGN.md §4) and why
-the long_500k shape runs here.
+Decode is the pure recurrence: O(1) state per token, which is why the
+long_500k shape runs here.  In a hybrid stack (granite-4.0-h) the serving
+cache holds two kinds of state side by side: K/V rows only for the
+attention layers, and this recurrent ``h`` and conv window only for the
+Mamba layers, each slot's fixed-size block read and written on every
+decode step (``PERF.md``, section 4, the granite-4.0-h-small cell).
 """
 
 from __future__ import annotations
@@ -198,6 +201,7 @@ def ssm_forward(
     dims: Dict[str, int],
     *,
     chunk: int = 128,
+    eps: float = 1e-6,
 ) -> jax.Array:
     z, xbc, dt_raw = _split_proj(params, x, dims)
     xbc = jax.nn.silu(_causal_depthwise_conv(xbc, params["conv_w"], params["conv_b"]))
@@ -207,7 +211,7 @@ def ssm_forward(
     y = y.reshape(b, s, dims["d_inner"])
     y = y + (params["D"].repeat(dims["head_dim"]) * xs.reshape(b, s, -1).astype(
         jnp.float32)).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), params["norm"])
+    y = rmsnorm(y * jax.nn.silu(z), params["norm"], eps)
     return jnp.einsum("bsi,id->bsd", y, params["out_proj"])
 
 
@@ -235,6 +239,7 @@ def ssm_step(
     x: jax.Array,  # [B,1,D]
     state: Dict[str, jax.Array],
     dims: Dict[str, int],
+    eps: float = 1e-6,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     b = x.shape[0]
     g, h = dims["n_groups"], dims["n_heads"]
@@ -258,6 +263,6 @@ def ssm_step(
     y = jnp.einsum("bhpn,bhn->bhp", new_h, c1)  # [B,H,P]
     y = y + params["D"][None, :, None] * x1
     y = y.reshape(b, 1, dims["d_inner"]).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), params["norm"])
+    y = rmsnorm(y * jax.nn.silu(z), params["norm"], eps)
     out = jnp.einsum("bsi,id->bsd", y, params["out_proj"])
     return out, {"h": new_h, "conv": new_conv}

@@ -11,6 +11,14 @@ layer — essential for 512-device dry-run compiles.  Heterogeneous layer
 patterns (gemma2 local/global alternation, hymba's three full-attention
 layers) are expressed as *per-layer scanned scalars* (attention window
 sizes), keeping the scanned computation uniform.
+
+An ``interleaved`` stack (granite-4.0-h) has layers of different kinds in
+sequence, read from ``layer_types``: Mamba-2 layers and attention layers,
+each followed by an expert layer and a shared MLP.  Its params are stacked
+per kind, and it scans over whole periods of the pattern (an inner scan
+per run of one kind), so its program is O(1) in depth too.  Its decode
+state holds K/V only for the attention layers and SSM state only for the
+Mamba layers, both carried through the scan and updated in place.
 """
 
 from __future__ import annotations
@@ -37,6 +45,10 @@ from repro.models.layers import (
 )
 
 FULL_WINDOW = 1 << 30  # "window" larger than any sequence = dense attention
+#: Query rows per block in an interleaved stack's prefill attention: its
+#: prompts are multiples of the Mamba chunk (256), and 512-row blocks keep
+#: a 7680-token prompt's float32 scores at 0.5 GB.
+IL_Q_BLOCK = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +61,7 @@ class ModelConfig:
     head_dim: int
     d_ff: int
     vocab: int
-    block: str = "dense"  # dense | moe | ssm | hybrid
+    block: str = "dense"  # dense | moe | ssm | hybrid | interleaved
     # attention flavour
     rope_theta: Optional[float] = 10_000.0
     qkv_bias: bool = False
@@ -83,6 +95,16 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_expand: int = 2
     ssm_chunk: int = 128
+    # interleaved stacks (granite-4.0-h): each layer's kind, "mamba" or
+    # "attention", in order; the pattern repeats with some period
+    layer_types: Optional[Tuple[str, ...]] = None
+    #: the expert-parallel share this chip holds: experts [lo, hi) of
+    #: n_experts (None: all); the router still routes over all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0  # logits are divided by it
+    norm_eps: float = 1e-6  # interleaved stacks' RMSNorms
     # encoder-decoder (whisper)
     n_encoder_layers: int = 0
     encoder_seq: int = 1500  # whisper: 30 s of 10 ms frames after conv stub
@@ -108,6 +130,41 @@ class ModelConfig:
             d_state=self.ssm_state,
             n_groups=self.ssm_groups,
         )
+
+    @property
+    def interleaved(self) -> bool:
+        return self.block == "interleaved"
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def period(self) -> int:
+        """Layers in one repeat of ``layer_types``."""
+        t = self.layer_types
+        for p in range(1, len(t) + 1):
+            if len(t) % p == 0 and t == t[:p] * (len(t) // p):
+                return p
+        raise ValueError(t)
+
+    def kind_count(self, kind: str) -> int:
+        return sum(k == kind for k in self.layer_types)
+
+    def segments(self) -> Tuple[Tuple[str, int, int, int], ...]:
+        """Runs of one kind in a period: (kind, index of the run's first
+        layer among the period's layers of that kind, run length, its
+        position in the period)."""
+        out, seen = [], {}
+        kinds = self.layer_types[:self.period]
+        start = 0
+        for i in range(1, len(kinds) + 1):
+            if i == len(kinds) or kinds[i] != kinds[start]:
+                k = kinds[start]
+                out.append((k, seen.get(k, 0), i - start, start))
+                seen[k] = seen.get(k, 0) + i - start
+                start = i
+        return tuple(out)
 
     @property
     def paired(self) -> bool:
@@ -139,6 +196,9 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (for 6·N·D roofline bookkeeping)."""
+        if self.interleaved:  # every parameter, from the shapes
+            specs = TransformerLM(self).param_specs()
+            return sum(int(x.size) for x in jax.tree.leaves(specs))
         d, f, L = self.d_model, self.d_ff, self.n_layers
         n = self.vocab * d  # embed
         if not self.tied_embeddings:
@@ -199,6 +259,11 @@ class DecodeState:
     ssm: Optional[Dict[str, jax.Array]]  # h: [L,B,H,P,N]; conv: [L,B,K-1,C]
     cross_kv: Optional[Dict[str, jax.Array]]  # whisper: [L,B,T_enc,Hkv,Dh]
     length: jax.Array  # [] int32: tokens already decoded
+    #: interleaved stacks: per layer, the held experts the last step's
+    #: tokens were routed to ([L] int32).  Interleaved stacks also keep K/V
+    #: of their attention layers alone (L = attention layers) and SSM
+    #: state of their Mamba layers alone (L = Mamba layers).
+    experts_touched: Optional[jax.Array] = None
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +371,28 @@ class TransformerLM:
         return self._sublayer_init(key, cfg.n_scan, ffn=ffn, d_ff=cfg.d_ff,
                                    cross=cross)
 
+    def _kind_init(self, key, kind: str, count: int) -> Tuple[Params, Axes]:
+        """``count`` stacked layers of one kind of an interleaved stack:
+        the mixer (Mamba-2 or attention), then the expert layer with its
+        shared MLP, each after an RMSNorm."""
+        cfg = self.cfg
+        km, ke = jax.random.split(key)
+        norm = lambda: jnp.zeros((count, cfg.d_model), cfg.dtype)  # noqa: E731
+        norm_ax = ("layers", "embed")
+        params: Params = {"pre_norm": norm(), "post_norm": norm()}
+        axes: Axes = {"pre_norm": norm_ax, "post_norm": norm_ax}
+        if kind == "mamba":
+            params["ssm"], axes["ssm"] = ssm_lib.ssm_init(
+                km, cfg.d_model, cfg.ssm_dims, cfg.dtype, stacked=count)
+        else:
+            params["attn"], axes["attn"] = attn.attention_init(
+                km, cfg.d_model, cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim,
+                cfg.dtype, stacked=count, qkv_bias=cfg.qkv_bias)
+        params["moe"], axes["moe"] = moe_lib.held_moe_init(
+            ke, cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held, cfg.dtype,
+            stacked=count, shared_expert_ff=cfg.shared_expert_ff)
+        return params, axes
+
     def init(self, key) -> Tuple[Params, Axes]:
         cfg = self.cfg
         k_embed, k_layers, k_enc, k_head = jax.random.split(key, 4)
@@ -313,9 +400,17 @@ class TransformerLM:
         axes: Axes = {}
         params["embed"] = embed_init(k_embed, (cfg.vocab, cfg.d_model), cfg.dtype)
         axes["embed"] = ("vocab", "embed")
-        params["layers"], axes["layers"] = self._layer_init(
-            k_layers, cross=cfg.n_encoder_layers > 0
-        )
+        if cfg.interleaved:
+            params["layers"], axes["layers"] = {}, {}
+            for i, kind in enumerate(("mamba", "attention")):
+                if cfg.kind_count(kind):
+                    params["layers"][kind], axes["layers"][kind] = \
+                        self._kind_init(jax.random.fold_in(k_layers, i),
+                                        kind, cfg.kind_count(kind))
+        else:
+            params["layers"], axes["layers"] = self._layer_init(
+                k_layers, cross=cfg.n_encoder_layers > 0
+            )
         if cfg.n_encoder_layers:
             params["enc_layers"], axes["enc_layers"] = self._sublayer_init(
                 k_enc, cfg.n_encoder_layers, ffn="mlp", d_ff=cfg.d_ff,
@@ -371,7 +466,8 @@ class TransformerLM:
             x = x + m
         return x, aux
 
-    def _ssm_forward_branch(self, layer: Params, h: jax.Array
+    def _ssm_forward_branch(self, layer: Params, h: jax.Array,
+                            eps: float = 1e-6
                             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """Full-sequence SSM branch; returns (out, final ssm state pieces)."""
         cfg = self.cfg
@@ -388,7 +484,7 @@ class TransformerLM:
         y = y.reshape(b, s, dims["d_inner"])
         y = y + (layer["ssm"]["D"].repeat(dims["head_dim"])
                  * xs_.reshape(b, s, -1).astype(jnp.float32)).astype(h.dtype)
-        y = rmsnorm(y * jax.nn.silu(z), layer["ssm"]["norm"])
+        y = rmsnorm(y * jax.nn.silu(z), layer["ssm"]["norm"], eps)
         out = jnp.einsum("bsi,id->bsd", y, layer["ssm"]["out_proj"])
         state = {"h": hfinal, "conv": xbc[:, -(dims["d_conv"] - 1):, :]}
         return out, state
@@ -471,6 +567,8 @@ class TransformerLM:
         x = embed_lookup(params["embed"], tokens)
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         if frontend_embeds is not None and cfg.frontend == "vision":
             # VLM early fusion: precomputed patch embeddings (stubbed
             # InternViT output) replace the first frontend_seq positions.
@@ -528,9 +626,13 @@ class TransformerLM:
         if cfg.n_encoder_layers:
             assert frontend_embeds is not None, "enc-dec needs frontend frames"
             memory_kv = self._cross_memory(params, frontend_embeds)
-        x, aux = self._run_stack(params["layers"], x, positions,
-                                 cfg.window_sizes(), memory_kv=memory_kv)
-        x = self._norm(x, params["final_norm"])
+        if cfg.interleaved:
+            x, _, _ = self._il_full(params, x, positions, None)
+            aux = jnp.zeros((), jnp.float32)
+        else:
+            x, aux = self._run_stack(params["layers"], x, positions,
+                                     cfg.window_sizes(), memory_kv=memory_kv)
+        x = self._final_norm(params, x)
         if last_only:
             return self._logits(params, x[:, -1:, :]), aux
         return x, aux
@@ -541,6 +643,8 @@ class TransformerLM:
         logits = unembed(x, table)
         if cfg.final_softcap:
             logits = softcap(logits, cfg.final_softcap)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         return logits
 
     def logits(self, params: Params, hidden: jax.Array) -> jax.Array:
@@ -549,6 +653,8 @@ class TransformerLM:
     # ---------------------------------------------------------------- serving
     def init_decode_state(self, batch: int, max_len: int) -> DecodeState:
         cfg = self.cfg
+        if cfg.interleaved:
+            return self._il_init_state(batch, max_len)
         kv = ssm_state = cross_kv = None
         if cfg.uses_attention:
             shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
@@ -578,6 +684,13 @@ class TransformerLM:
             "h": ("layers", "batch", "ssm_heads", "ssm_head_dim", "ssm_state"),
             "conv": ("layers", "batch", "conv", "ssm_conv_dim"),
         }
+        if cfg.interleaved:
+            return DecodeState(
+                kv=kv_ax if cfg.kind_count("attention") else None,
+                ssm=ssm_ax if cfg.kind_count("mamba") else None,
+                cross_kv=None, length=("batch",),
+                experts_touched=("layers",),
+            )
         return DecodeState(
             kv=kv_ax if cfg.uses_attention else None,
             ssm=ssm_ax if cfg.uses_ssm else None,
@@ -637,6 +750,8 @@ class TransformerLM:
     ) -> Tuple[jax.Array, DecodeState]:
         """One decode step: (logits [B, V], new state)."""
         cfg = self.cfg
+        if cfg.interleaved:
+            return self._il_decode_step(params, state, token)
         x = embed_lookup(params["embed"], token[:, None])  # [B,1,D]
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
@@ -748,6 +863,15 @@ class TransformerLM:
         b, s = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         x = self._embed_inputs(params, tokens, frontend_embeds)
+        if cfg.interleaved:
+            x, st, touched = self._il_full(
+                params, x, positions, {"attention": state.kv,
+                                       "mamba": state.ssm})
+            x = self._final_norm(params, x)
+            logits = self._logits(params, x[:, -1:, :])[:, 0, :]
+            return logits, DecodeState(
+                kv=st["attention"], ssm=st["mamba"], cross_kv=None,
+                length=jnp.full((b,), s, jnp.int32), experts_touched=touched)
         windows = cfg.window_sizes()
         memory_kv = None
         if cfg.n_encoder_layers:
@@ -801,6 +925,145 @@ class TransformerLM:
             length=jnp.full((b,), s, jnp.int32),
         )
         return logits, new_state
+
+    # ------------------------------------------------- interleaved stacks
+    def _final_norm(self, params: Params, x: jax.Array) -> jax.Array:
+        if self.cfg.interleaved:
+            return rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
+        return self._norm(x, params["final_norm"])
+
+    def _il_init_state(self, batch: int, max_len: int) -> DecodeState:
+        cfg = self.cfg
+        n_attn, n_mamba = cfg.kind_count("attention"), cfg.kind_count("mamba")
+        kv = ssm_state = None
+        if n_attn:
+            shape = (n_attn, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+            kv = {"k": jnp.zeros(shape, cfg.dtype),
+                  "v": jnp.zeros(shape, cfg.dtype)}
+        if n_mamba:
+            dims = cfg.ssm_dims
+            ssm_state = {
+                "h": jnp.zeros((n_mamba, batch, dims["n_heads"],
+                                dims["head_dim"], dims["d_state"]), jnp.float32),
+                "conv": jnp.zeros((n_mamba, batch, dims["d_conv"] - 1,
+                                   dims["conv_dim"]), cfg.dtype),
+            }
+        return DecodeState(kv=kv, ssm=ssm_state, cross_kv=None,
+                           length=jnp.zeros((batch,), jnp.int32),
+                           experts_touched=jnp.zeros((cfg.n_layers,),
+                                                     jnp.int32))
+
+    def _il_stack(self, layers: Params, x: jax.Array, state: Dict[str, Any],
+                  mixer):
+        """Run an interleaved stack: a scan over its periods, and in each an
+        inner scan per run of one kind.  Each layer is an RMSNorm, its
+        mixer ``mixer(kind, layer_params, h, kind_state, index_in_kind) ->
+        (out, kind_state)`` added to the residual times
+        ``residual_multiplier``, then the expert layer.  ``state`` maps
+        each kind to its stacked state (or None), carried and updated in
+        place.  Returns (x, state, held experts touched [n_layers])."""
+        cfg = self.cfg
+        per_kind = {k: sum(t == k for t in cfg.layer_types[:cfg.period])
+                    for k in ("mamba", "attention")}
+        touched0 = jnp.zeros((cfg.n_layers,), jnp.int32)
+        res = cfg.residual_multiplier
+
+        def period_body(carry, p):
+            for kind, first, count, at in cfg.segments():
+                def layer_body(c, j, kind=kind, first=first, at=at):
+                    x1, st, touched = c
+                    i = p * per_kind[kind] + first + j
+                    lp = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, i, keepdims=False), layers[kind])
+                    x1 = constrain(x1, ("batch", "seq", "embed_act"))
+                    h = rmsnorm(x1, lp["pre_norm"], cfg.norm_eps)
+                    out, st_k = mixer(kind, lp, h, st[kind], i)
+                    x1 = x1 + out * jnp.asarray(res, x1.dtype)
+                    x1, t = self._il_ffn(lp, x1)
+                    touched = touched.at[p * cfg.period + at + j].set(t)
+                    return (x1, {**st, kind: st_k}, touched), None
+
+                carry, _ = jax.lax.scan(self._maybe_remat(layer_body), carry,
+                                        jnp.arange(count))
+            return carry, None
+
+        (x, state, touched), _ = jax.lax.scan(
+            period_body, (x, state, touched0),
+            jnp.arange(cfg.n_layers // cfg.period))
+        return x, state, touched
+
+    def _il_ffn(self, lp: Params, x: jax.Array):
+        cfg = self.cfg
+        h = rmsnorm(x, lp["post_norm"], cfg.norm_eps)
+        with jax.named_scope("moe"):
+            m, touched = moe_lib.held_moe_apply(
+                lp["moe"], h, top_k=cfg.top_k, held=cfg.held,
+                activation=cfg.activation)
+        return x + m * jnp.asarray(cfg.residual_multiplier, x.dtype), touched
+
+    def _il_full(self, params: Params, x: jax.Array, positions: jax.Array,
+                 state: Optional[Dict[str, Any]]):
+        """Whole-sequence pass (training forward, or prefill when ``state``
+        holds the stacked K/V and SSM state to fill)."""
+        cfg = self.cfg
+
+        def mixer(kind, lp, h, st, i):
+            if kind == "mamba":
+                with jax.named_scope("ssm"):
+                    out, new = self._ssm_forward_branch(lp, h,
+                                                        eps=cfg.norm_eps)
+                if st is not None:
+                    st = {k: jax.lax.dynamic_update_index_in_dim(
+                        st[k], new[k].astype(st[k].dtype), i, 0) for k in st}
+            else:
+                with jax.named_scope("attn"):
+                    if st is not None:
+                        _, k, v = attn.project_qkv(lp["attn"], h, positions,
+                                                   rope_theta=cfg.rope_theta)
+                        st = {n: jax.lax.dynamic_update_slice(
+                            st[n], t.astype(st[n].dtype)[None],
+                            (i, 0, 0, 0, 0)) for n, t in (("k", k), ("v", v))}
+                    out = attn.attend_full(
+                        lp["attn"], h, positions, rope_theta=cfg.rope_theta,
+                        window=jnp.int32(FULL_WINDOW),
+                        query_scale=cfg.query_scale, q_block=IL_Q_BLOCK)
+            return out, st
+
+        st0 = state or {"mamba": None, "attention": None}
+        x, st, touched = self._il_stack(params["layers"], x, st0, mixer)
+        return x, (st if state is not None else None), touched
+
+    def _il_decode_step(self, params: Params, state: DecodeState,
+                        token: jax.Array) -> Tuple[jax.Array, DecodeState]:
+        cfg = self.cfg
+        length = state.length
+        x = self._embed_inputs(params, token[:, None], None)  # [B,1,D]
+
+        def mixer(kind, lp, h, st, i):
+            if kind == "mamba":
+                with jax.named_scope("ssm"):
+                    mine = {k: jax.lax.dynamic_index_in_dim(
+                        st[k], i, keepdims=False) for k in st}
+                    out, new = ssm_lib.ssm_step(lp["ssm"], h, mine,
+                                                cfg.ssm_dims, cfg.norm_eps)
+                    st = {k: jax.lax.dynamic_update_index_in_dim(
+                        st[k], new[k].astype(st[k].dtype), i, 0) for k in st}
+            else:
+                with jax.named_scope("attn"):
+                    out, st = attn.attend_cached_stacked(
+                        lp["attn"], h, st, i, length,
+                        rope_theta=cfg.rope_theta, query_scale=cfg.query_scale)
+            return out, st
+
+        x, st, touched = self._il_stack(
+            params["layers"], x, {"attention": state.kv, "mamba": state.ssm},
+            mixer)
+        x = self._final_norm(params, x)
+        logits = self._logits(params, x)[:, 0, :]
+        return logits, DecodeState(kv=st["attention"], ssm=st["mamba"],
+                                   cross_kv=None, length=length + 1,
+                                   experts_touched=touched)
 
 
 def _axes_of(model: "TransformerLM") -> Axes:

@@ -102,19 +102,28 @@ class ServingEngine:
         self._active = np.zeros((cfg.max_slots,), bool)
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
         self._prefill_cache: Dict[int, Callable] = {}
+        #: a decode step ran whose experts-touched counts are unread
+        self._touched_unread = False
 
-        # Tier accounting constants.
+        # Tier accounting constants, by layer kind: K/V rows of the
+        # attention layers per token, and the fixed-size recurrent state
+        # (SSM ``h`` and conv window) of the Mamba layers per slot.
         self.param_bytes = sum(
             int(np.prod(x.shape)) * x.dtype.itemsize
             for x in jax.tree.leaves(self.params)
         )
         cfgm = cfg.model
-        if cfgm.uses_attention:
-            self.kv_bytes_per_token = (
-                2 * cfgm.n_kv_heads * cfgm.head_dim * cfgm.n_layers * 2
-            )
+        if cfgm.interleaved:
+            n_attn = cfgm.kind_count("attention")
         else:
-            self.kv_bytes_per_token = 0
+            n_attn = cfgm.n_layers if cfgm.uses_attention else 0
+        self.kv_bytes_per_token = (
+            2 * cfgm.n_kv_heads * cfgm.head_dim * n_attn * 2
+        )
+        self.ssm_bytes_per_slot = sum(
+            x.size // x.shape[1] * x.dtype.itemsize
+            for x in jax.tree.leaves(self.state.ssm)
+        )
 
     def _place_state(self) -> None:
         if self.cfg.placement == "host":
@@ -169,7 +178,8 @@ class ServingEngine:
             ssm = {k: put(ssm[k], state1.ssm[k]) for k in ssm}
         length = st.length.at[slot].set(plen)
         self.state = DecodeState(kv=kv, ssm=ssm, cross_kv=st.cross_kv,
-                                 length=length)
+                                 length=length,
+                                 experts_touched=st.experts_touched)
 
     def admit(self, now_ns: float) -> List[Tuple[Request, int]]:
         """Prefill queued requests into free slots.  Returns admissions
@@ -189,7 +199,8 @@ class ServingEngine:
             self._tokens = self._tokens.at[slot].set(int(first[0]))
             self.slot_req[slot] = req
             self._active[slot] = True
-            admitted.append((req, plen * self.kv_bytes_per_token))
+            admitted.append((req, plen * self.kv_bytes_per_token
+                             + self.ssm_bytes_per_slot))
         return admitted
 
     def _sample(self, logits: jax.Array) -> jax.Array:
@@ -203,12 +214,28 @@ class ServingEngine:
         return int(self._active.sum())
 
     def step_bytes(self) -> Tuple[int, int]:
-        """(weight_bytes, kv_bytes) one decode step streams."""
+        """(weight_bytes, kv_bytes) one decode step streams: the K/V bytes
+        include each active slot's recurrent state, read and written.
+
+        The same read of the slots' lengths fetches the last decode step's
+        per-layer count of held experts its tokens were routed to, which
+        feeds the ``moe.experts_touched`` counter."""
         wb = int(self.param_bytes * self.cfg.weight_stream_fraction)
-        lengths = np.asarray(jax.device_get(self.state.length))
+        if self.state.experts_touched is None:
+            lengths = np.asarray(jax.device_get(self.state.length))
+        else:
+            lengths, touched = jax.device_get(
+                (self.state.length, self.state.experts_touched))
+            if self._touched_unread:
+                from repro.obs.metrics import default_registry
+
+                default_registry().counter("moe.experts_touched").inc(
+                    float(np.sum(touched)))
+                self._touched_unread = False
         kvb = int(
             sum(
                 int(lengths[i]) * self.kv_bytes_per_token
+                + 2 * self.ssm_bytes_per_slot
                 for i in range(self.cfg.max_slots)
                 if self._active[i]
             )
@@ -239,6 +266,7 @@ class ServingEngine:
             return 0
         logits, self.state = self._decode(self.step_params(), self.state,
                                           self._tokens)
+        self._touched_unread = self.state.experts_touched is not None
         nxt = self._sample(logits)
         self._tokens = nxt
         produced = 0

@@ -100,3 +100,29 @@ def test_qwen_serving_step_fits_one_v5e(one_chip, step):
     program = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert program <= V5E_HBM_BYTES
     assert program + weights <= V5E_HBM_BYTES
+
+
+def test_granite_share_decode_fits_one_v5e(one_chip):
+    """The granite-4.0-h-small share the chip benchmark serves (layers
+    0-19, experts 0-8 of 72): one decode step at 16 slots of 8192
+    positions, weights and the donated two-kind state included, fits one
+    chip's HBM and updates the state in place."""
+    import dataclasses
+
+    published = get_arch("granite-4.0-h-small").config
+    cfg = dataclasses.replace(published, n_layers=20,
+                              layer_types=published.layer_types[:20],
+                              experts_held=(0, 9))
+    model = TransformerLM(cfg)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))[0]))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: model.init_decode_state(16, 8192)))
+    token = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    mem = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, state, token).compile().memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes - 2 ** 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= V5E_HBM_BYTES
