@@ -5,6 +5,9 @@ Architecture
 ------------
 * :class:`ServingEngine` — one model instance: continuous batching over a
   fixed slot array, real jitted prefill/decode steps, per-slot lengths.
+  The engine keeps each slot's length on the host too (a mirror of the
+  state's ``length``), so a decode step makes one device read: the sampled
+  tokens, with the state's experts-touched counts where it carries them.
   The instance's *placement* decides which memory tier its weights live
   on: ``device`` (HBM — the DDR analogue) or ``host`` (pinned host memory
   over PCIe — the CXL analogue).  Host-placed weights are put on the
@@ -42,7 +45,14 @@ from repro.core.littles_law import OpClass
 from repro.core.offload import TransferQueue
 from repro.core.tiers import HBM_TIER, HOST_TIER, require_host_offload
 from repro.models.transformer import DecodeState, ModelConfig, TransformerLM
+from repro.obs.metrics import default_registry
 from repro.serving import sampler as sampler_lib
+
+
+def _read(x: Any) -> Any:
+    """One device -> host read, counted by ``serving.host_reads``."""
+    default_registry().counter("serving.host_reads").inc()
+    return jax.device_get(x)
 
 
 @dataclasses.dataclass
@@ -100,10 +110,10 @@ class ServingEngine:
         self.done: List[Request] = []
         self._tokens = jnp.zeros((cfg.max_slots,), jnp.int32)
         self._active = np.zeros((cfg.max_slots,), bool)
+        #: host mirror of ``state.length``: set at admission, +1 per step
+        self._lengths = np.zeros((cfg.max_slots,), np.int32)
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
         self._prefill_cache: Dict[int, Callable] = {}
-        #: a decode step ran whose experts-touched counts are unread
-        self._touched_unread = False
 
         # Tier accounting constants, by layer kind: K/V rows of the
         # attention layers per token, and the fixed-size recurrent state
@@ -177,6 +187,7 @@ class ServingEngine:
         if ssm is not None:
             ssm = {k: put(ssm[k], state1.ssm[k]) for k in ssm}
         length = st.length.at[slot].set(plen)
+        self._lengths[slot] = plen
         self.state = DecodeState(kv=kv, ssm=ssm, cross_kv=st.cross_kv,
                                  length=length,
                                  experts_touched=st.experts_touched)
@@ -193,10 +204,10 @@ class ServingEngine:
             tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
             logits, state1 = self._prefill_fn(plen)(self.step_params(), tokens)
             first = self._sample(logits)
-            req.output.append(int(first[0]))
+            req.output.append(int(_read(first)[0]))
             req.t_first_token = now_ns
             self._insert_state(slot, state1, plen)
-            self._tokens = self._tokens.at[slot].set(int(first[0]))
+            self._tokens = self._tokens.at[slot].set(first[0])
             self.slot_req[slot] = req
             self._active[slot] = True
             admitted.append((req, plen * self.kv_bytes_per_token
@@ -216,30 +227,11 @@ class ServingEngine:
     def step_bytes(self) -> Tuple[int, int]:
         """(weight_bytes, kv_bytes) one decode step streams: the K/V bytes
         include each active slot's recurrent state, read and written.
-
-        The same read of the slots' lengths fetches the last decode step's
-        per-layer count of held experts its tokens were routed to, which
-        feeds the ``moe.experts_touched`` counter."""
+        Lengths come from the host mirror: no device read."""
         wb = int(self.param_bytes * self.cfg.weight_stream_fraction)
-        if self.state.experts_touched is None:
-            lengths = np.asarray(jax.device_get(self.state.length))
-        else:
-            lengths, touched = jax.device_get(
-                (self.state.length, self.state.experts_touched))
-            if self._touched_unread:
-                from repro.obs.metrics import default_registry
-
-                default_registry().counter("moe.experts_touched").inc(
-                    float(np.sum(touched)))
-                self._touched_unread = False
-        kvb = int(
-            sum(
-                int(lengths[i]) * self.kv_bytes_per_token
-                + 2 * self.ssm_bytes_per_slot
-                for i in range(self.cfg.max_slots)
-                if self._active[i]
-            )
-        )
+        live = int(self._lengths[self._active].sum(dtype=np.int64))
+        kvb = (live * self.kv_bytes_per_token
+               + 2 * self.ssm_bytes_per_slot * self.n_active)
         return wb, kvb
 
     def kv_tier_bytes(self, kv_bytes: int) -> Tuple[int, int]:
@@ -261,22 +253,33 @@ class ServingEngine:
         return fast_bytes, kv_bytes - fast_bytes
 
     def decode_once(self, now_ns: float) -> int:
-        """One real decode step for all active slots.  Returns #tokens."""
+        """One real decode step for all active slots.  Returns #tokens.
+
+        One device read: the sampled tokens, and the step's per-layer count
+        of held experts its tokens were routed to where the state carries
+        it (the ``moe.experts_touched`` counter)."""
         if self.n_active == 0:
             return 0
         logits, self.state = self._decode(self.step_params(), self.state,
                                           self._tokens)
-        self._touched_unread = self.state.experts_touched is not None
         nxt = self._sample(logits)
         self._tokens = nxt
+        self._lengths += 1  # decode_step's length + 1, on every slot
+        touched = self.state.experts_touched
+        if touched is None:
+            toks = _read(nxt)
+        else:
+            toks, touched = _read((nxt, touched))
+            default_registry().counter("moe.experts_touched").inc(
+                float(np.sum(touched)))
         produced = 0
         for slot, req in enumerate(self.slot_req):
             if req is None:
                 continue
-            req.output.append(int(nxt[slot]))
+            req.output.append(int(toks[slot]))
             produced += 1
             done = len(req.output) >= req.max_new_tokens
-            overflow = int(self.state.length[slot]) >= self.cfg.max_len - 1
+            overflow = self._lengths[slot] >= self.cfg.max_len - 1
             if done or overflow:
                 req.t_done = now_ns
                 self.done.append(req)
@@ -390,8 +393,6 @@ class TieredServingCluster:
                  **{f"tok_{k}": float(v) for k, v in produced.items()}}
             )
         out: Dict[str, Dict[str, float]] = {}
-        from repro.obs.metrics import default_registry
-
         reg = default_registry()
         for eng in self.engines:
             name = eng.cfg.name

@@ -242,17 +242,47 @@ def test_engine_charges_state_by_layer_kind():
                  + (dims["d_conv"] - 1) * dims["conv_dim"] * 2)
     assert eng.ssm_bytes_per_slot == state
     counter = default_registry().counter("moe.experts_touched")
-    before = counter.value
     for i in range(2):
         eng.submit(Request(rid=i, prompt=[1, 2, 3, 4, 5], max_new_tokens=3))
     cluster = TieredServingCluster([eng])
-    cluster.run(max_ticks=1)
-    wb, kvb = eng.step_bytes()
-    assert wb == eng.param_bytes
-    assert kvb == 2 * (6 * eng.kv_bytes_per_token + 2 * state)
-    assert counter.value > before
-    cluster.run(max_ticks=10)
-    assert len(eng.done) == 2
+    steps = 0
+    while not eng.finished:
+        before = counter.value
+        cluster.run(max_ticks=1)  # one decode step: the queue is admitted
+        steps += 1
+        touched = np.sum(jax.device_get(eng.state.experts_touched))
+        assert counter.value - before == touched > 0
+        if steps == 1:
+            wb, kvb = eng.step_bytes()
+            assert wb == eng.param_bytes
+            assert kvb == 2 * (6 * eng.kv_bytes_per_token + 2 * state)
+    assert steps == 2 and len(eng.done) == 2
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "granite-4.0-h-small"])
+def test_engine_lengths_mirror_the_device(arch):
+    """After every tick the host mirror of the slots' lengths equals the
+    state's ``length``, and ``step_bytes`` gives what the formula over the
+    device's lengths gives, through admissions, completions and an
+    overflow of ``max_len``."""
+    eng = _engine(get_arch(arch).smoke, slots=3)
+    for i, (plen, n) in enumerate([(5, 3), (9, 60), (3, 6), (7, 2), (4, 5)]):
+        eng.submit(Request(rid=i, prompt=list(range(1, plen + 1)),
+                           max_new_tokens=n))
+    cluster = TieredServingCluster([eng])
+    ticks = 0
+    while not eng.finished:
+        cluster.run(max_ticks=1)
+        ticks += 1
+        lengths = np.asarray(jax.device_get(eng.state.length))
+        np.testing.assert_array_equal(eng._lengths, lengths)
+        kvb = sum(int(lengths[i]) * eng.kv_bytes_per_token
+                  + 2 * eng.ssm_bytes_per_slot
+                  for i in range(3) if eng._active[i])
+        assert eng.step_bytes() == (eng.param_bytes, kvb)
+    assert len(eng.done) == 5
+    long = next(r for r in eng.done if r.rid == 1)
+    assert len(long.output) == 64 - 9  # stopped by max_len, not by 60
 
 
 def test_dense_engine_accounting_is_unchanged():

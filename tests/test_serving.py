@@ -1,6 +1,9 @@
 """Serving engine + tiered cluster behaviour."""
 
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_arch
@@ -86,3 +89,48 @@ def test_host_only_ticks_jump_to_the_next_completion():
     res = TieredServingCluster([eng]).run(60)
     assert res["h"]["requests"] == 2
     assert all(len(r.output) == 8 for r in eng.done)
+
+
+def test_one_host_read_per_decode_step_and_admission():
+    """A 4-slot engine through admissions, completions and an overflow
+    (``max_len`` 24) reads the device once per decode step and once per
+    admission, whatever the slot count; every finished request's output is
+    a plain greedy decode of its prompt by the model itself."""
+    from repro.obs.metrics import default_registry
+
+    cfg = dataclasses.replace(CFG, dtype=jnp.float32)
+    model = TransformerLM(cfg)
+    params, _ = model.init(jax.random.PRNGKey(1))
+    max_len = 24
+    eng = ServingEngine(
+        EngineConfig(name="e", model=cfg, max_slots=4, max_len=max_len),
+        params,
+    )
+    # (prompt length, max_new_tokens): 7 requests over 4 slots; the last
+    # two run into max_len before max_new_tokens.
+    shapes = [(3, 4), (5, 6), (8, 2), (4, 5), (6, 3), (12, 30), (9, 40)]
+    for i, (plen, n) in enumerate(shapes):
+        eng.submit(Request(rid=i, prompt=[(7 * i + j) % cfg.vocab + 1
+                                          for j in range(plen)],
+                           max_new_tokens=n))
+    reads = default_registry().counter("serving.host_reads")
+    steps = 0
+    while not eng.finished:
+        before = reads.value
+        admitted = eng.admit(0.0)
+        assert reads.value - before == len(admitted)
+        before = reads.value
+        produced = eng.decode_once(0.0)
+        assert reads.value - before == (1 if produced else 0)
+        steps += 1
+    assert steps > 1 and len(eng.done) == len(shapes)
+
+    fwd = jax.jit(lambda p, t: model.forward(p, t, last_only=True)[0])
+    for r in eng.done:
+        plen, n = shapes[r.rid]
+        assert len(r.output) == min(n, max_len - plen)
+        seq = list(r.prompt)
+        for _ in r.output:
+            logits = fwd(params, jnp.asarray([seq], jnp.int32))
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert r.output == seq[plen:], (r.rid, r.output, seq[plen:])
