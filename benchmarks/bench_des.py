@@ -1,23 +1,21 @@
-"""DES microbenchmark: fast-path rewrite vs the seed DES, interleaved A/B.
+"""DES and batched-lane host benchmark, with the Fig. 3/5 golden check.
 
-Measures the fig5 co-run config (LOAD, 16+16 threads, 300 us simulated) on
-both the current DES and the pinned seed snapshot
-(``benchmarks/_seed_des_baseline.py``), alternating reps so container CPU
-throttling hits both sides equally, and verifies the Fig. 3/5 bandwidths
-against the recorded seed goldens (they are bit-identical by construction;
-1% is the gate).  Also runs the sweep-scale lane A/B: the 96-cell
-``corun_sweep`` grid on the scalar process pool vs the batched lane
-(``repro.memsim.batched``; ≥5x is the acceptance bar, with the cross-lane
-bandwidth deviation recorded alongside), and the kilo-cell A/B/C: the
-1024-cell ``corun_sweep_1k`` grid on the scalar pool vs the batched lane
-under both solver backends (numpy and the fused jit/Pallas window solver,
-``REPRO_BATCH_BACKEND=pallas``; the gate bounds control-decision flips
-and the decision-aligned p95 bandwidth deviation — see
-``_SWEEP1K_MAX_FLIPS``).  Emits ``BENCH_des.json`` at the repo root.
+Verifies the Fig. 3/5 bandwidths of the DES against the recorded seed
+goldens (they are bit-identical by construction; 1% is the gate).  Also
+runs the sweep-scale lane A/B: the 96-cell ``corun_sweep`` grid on the
+scalar process pool vs the batched lane (``repro.memsim.batched``; ≥5x is
+the acceptance bar, with the cross-lane bandwidth deviation recorded
+alongside), the kilo-cell A/B/C: the 1024-cell ``corun_sweep_1k`` grid on
+the scalar pool vs the batched lane under both window solvers (numpy and
+the fused jit/Pallas solver, each forced for its run through
+``kernel.uses_fused_solver``; the gate bounds control-decision flips and
+the decision-aligned p95 bandwidth deviation — see
+``_SWEEP1K_MAX_FLIPS``), and the observability overhead A/B.  Emits
+``BENCH_des.json`` at the repo root.
 
 Usage:  PYTHONPATH=src python benchmarks/bench_des.py [--reps N] [--out PATH]
         PYTHONPATH=src python benchmarks/bench_des.py --sweep-1k   # CI slow
-        PYTHONPATH=src python benchmarks/bench_des.py --smoke      # CI fast
+        PYTHONPATH=src python benchmarks/bench_des.py --obs        # CI fast
 """
 
 from __future__ import annotations
@@ -25,9 +23,9 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import statistics
 import sys
 import time
+from unittest import mock
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO_ROOT = os.path.dirname(_HERE)
@@ -38,44 +36,11 @@ from repro.core.des import run_bw_test, run_corun
 from repro.core.device_model import platform_a
 from repro.core.littles_law import OpClass
 
-from benchmarks import _seed_des_baseline as seed_des
-
 _GOLDENS = os.path.join(_REPO_ROOT, "tests", "data", "seed_fig_goldens.json")
 
 #: Every time below is host wall seconds: the DES is host Python, and the
 #: Pallas solver runs interpreted unless JAX's platform is the TPU.
 _CLOCK = "host CPU wall seconds; Pallas interpreted off-TPU"
-
-
-def _time(fn) -> float:
-    t0 = time.perf_counter()
-    fn()
-    return time.perf_counter() - t0
-
-
-def bench_ab(reps: int) -> dict:
-    p = platform_a()
-    kw = dict(op=OpClass.LOAD, n_threads=16, sim_ns=300_000)
-    seed_t, new_t = [], []
-    completed = 0
-    for _ in range(reps):
-        seed_t.append(_time(lambda: seed_des.run_corun(p, **kw)))
-        t0 = time.perf_counter()
-        res = run_corun(p, **kw)
-        new_t.append(time.perf_counter() - t0)
-        completed = sum(s.completed for s in res.stats.values())
-    return {
-        "config": "fig5_corun_load_16t_300us",
-        "seed_wall_s": {"best": round(min(seed_t), 4),
-                        "median": round(statistics.median(seed_t), 4)},
-        "corun_wall_s": {"best": round(min(new_t), 4),
-                         "median": round(statistics.median(new_t), 4)},
-        "speedup_vs_seed": round(min(seed_t) / min(new_t), 2),
-        "speedup_vs_seed_median": round(
-            statistics.median(seed_t) / statistics.median(new_t), 2),
-        "events_per_s": int(completed / min(new_t)),
-        "completed_requests": completed,
-    }
 
 
 def check_goldens() -> dict:
@@ -185,7 +150,7 @@ def lane_gate(ref, cand) -> dict:
 def bench_sweep_1k() -> dict:
     """Kilo-cell lane A/B/C: the 1024-cell ``corun_sweep_1k`` grid on the
     scalar pool, the batched numpy lane, and the batched lane with the
-    fused jit/Pallas window solver (``REPRO_BATCH_BACKEND=pallas``).
+    fused jit/Pallas window solver, whatever the platform would pick.
 
     Each batched side runs twice and keeps the warm time (the first pallas
     call pays jit tracing).  Gates each backend against the scalar DES on
@@ -194,6 +159,7 @@ def bench_sweep_1k() -> dict:
     import os as _os
 
     from repro.core.controller import Phase
+    from repro.memsim.batched import kernel
     from repro.memsim.sweep import run_sweep
     from repro.scenarios import plan
 
@@ -208,16 +174,10 @@ def bench_sweep_1k() -> dict:
         run_sweep(jobs, lane="batched")
         return res, min(t_cold, time.perf_counter() - t0)
 
-    numpy_res, t_numpy = timed_batched()
-    prev = _os.environ.get("REPRO_BATCH_BACKEND")
-    _os.environ["REPRO_BATCH_BACKEND"] = "pallas"
-    try:
+    with mock.patch.object(kernel, "uses_fused_solver", lambda: False):
+        numpy_res, t_numpy = timed_batched()
+    with mock.patch.object(kernel, "uses_fused_solver", lambda: True):
         pallas_res, t_pallas = timed_batched()
-    finally:
-        if prev is None:
-            _os.environ.pop("REPRO_BATCH_BACKEND", None)
-        else:
-            _os.environ["REPRO_BATCH_BACKEND"] = prev
     t0 = time.perf_counter()
     scalar = run_sweep(jobs, processes=procs, lane="scalar")
     t_scalar = time.perf_counter() - t0
@@ -298,35 +258,10 @@ def bench_obs(reps: int = 4) -> dict:
     }
 
 
-def check_fast_path_overhead(out: dict, snapshot_path: str) -> dict:
-    """Two-tier fast-path overhead gate for the per-tier contract.
-
-    Compares this run's interleaved A/B speedup-vs-seed against the
-    committed BENCH_des.json snapshot's.  The speedup ratio is
-    machine-robust (both sides of each A/B pair ran on the same box), so a
-    drop > 5% means the control-plane change itself slowed the two-tier
-    hot path."""
-    try:
-        with open(snapshot_path) as f:
-            snap = json.load(f)
-        snap_speedup = float(snap["speedup_vs_seed"])
-    except (OSError, KeyError, ValueError):
-        return {"fast_path_overhead_pct": None, "fast_path_within_5pct": True}
-    overhead = (snap_speedup / max(out["speedup_vs_seed"], 1e-9) - 1.0) * 100.0
-    return {
-        "snapshot_speedup_vs_seed": snap_speedup,
-        "fast_path_overhead_pct": round(overhead, 2),
-        "fast_path_within_5pct": overhead < 5.0,
-    }
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=6)
     ap.add_argument("--out", default=os.path.join(_REPO_ROOT, "BENCH_des.json"))
-    ap.add_argument("--smoke", action="store_true",
-                    help="quick 2-rep timing print (no file write) — the CI "
-                         "gating-lane smoke")
     ap.add_argument("--sweep-1k", action="store_true",
                     help="run only the 1024-cell grid A/B/C (numpy + pallas "
                          "batched vs scalar pool; no file write) and gate on "
@@ -340,12 +275,6 @@ def main() -> None:
     from repro.launch.compile_cache import use_compile_cache
 
     use_compile_cache()
-    snapshot = os.path.join(_REPO_ROOT, "BENCH_des.json")
-    if args.smoke:
-        out = {"bench": "des_fast_path_smoke", **bench_ab(2)}
-        out.update(check_fast_path_overhead(out, snapshot))
-        print(json.dumps(out, indent=2))
-        return
     if args.obs:
         out = {"bench": "des_obs_overhead", **bench_obs(max(args.reps, 3))}
         print(json.dumps(out, indent=2))
@@ -369,16 +298,11 @@ def main() -> None:
             print("WARNING: batched lane below the 5x acceptance bar on "
                   "the 1024-cell grid (noisy machine, or a regression)")
         return
-    out = {"bench": "des_fast_path", "clock": _CLOCK,
-           **bench_ab(args.reps), **check_goldens()}
-    out.update(check_fast_path_overhead(out, snapshot))
+    out = {"bench": "des", "clock": _CLOCK, **check_goldens()}
     out["sweep_lanes"] = bench_sweep_lanes()
     out["sweep_1k"] = bench_sweep_1k()
     out["observability"] = bench_obs(args.reps)
     print(json.dumps(out, indent=2))
-    if out["speedup_vs_seed"] < 2.0:
-        print("WARNING: speedup below the 2x acceptance bar "
-              "(noisy machine, or a fast-path regression)")
     if not out["sweep_lanes"]["batched_speedup_ge_5x"]:
         print("WARNING: batched lane below the 5x acceptance bar vs the "
               "scalar pool (noisy machine, or a batched-lane regression)")
@@ -397,13 +321,6 @@ def main() -> None:
     assert out["observability"]["obs_within_10pct"], (
         f"observability added {out['observability']['obs_overhead_pct']}% "
         "on the co-run config (>10% budget); snapshot left untouched"
-    )
-    # Gate BEFORE writing: a failing run must not replace the snapshot it
-    # was compared against (the baseline would self-ratchet downward).
-    assert out["fast_path_within_5pct"], (
-        f"per-tier contract added {out['fast_path_overhead_pct']}% on the "
-        "two-tier fast path vs the BENCH_des.json snapshot (>5% budget); "
-        "snapshot left untouched"
     )
     with open(args.out, "w") as f:
         json.dump(out, f, indent=2)

@@ -67,13 +67,13 @@ def _module_names() -> list:
 def _uses_device(name: str) -> bool:
     """Whether a figure module touches the accelerator: one presenting a
     ``device`` scenario always does, and every module does when its sweeps
-    run the batched lane on the Pallas solver."""
-    from repro.memsim.batched.kernel import backend
+    run the batched lane on the fused window solver."""
+    from repro.memsim.batched import kernel
     from repro.memsim.sweep import default_lane
     from repro.scenarios import all_scenarios
 
     return any(sc.device for sc in all_scenarios() if sc.module == name) or (
-        default_lane() == "batched" and backend() == "pallas"
+        default_lane() == "batched" and kernel.uses_fused_solver()
     )
 
 

@@ -8,12 +8,12 @@ Phases, in order (each prints its readings; any failure exits non-zero):
    is refused; there is no CPU fallback.
 2. Lane: ``run_scenario("corun_sweep_1k", lane="batched")``, the planner
    entry behind ``benchmarks/run.py --scenario corun_sweep_1k --lane
-   batched``, once with the fused Pallas window solver
-   (``REPRO_BATCH_BACKEND=pallas``) and once with the numpy solver, each
-   cold and warm.  No cell may fall back to the scalar DES, the two solvers
-   must pass the kilo-grid gate of ``benchmarks/bench_des.py``, and the
-   solver's compiled program must hold the Pallas kernel as a TPU custom
-   call (compiled, not interpreted).
+   batched``, once with the fused Pallas window solver (the platform's
+   pick on the TPU) and once with the numpy solver forced in its place,
+   each cold and warm.  No cell may fall back to the scalar DES, the two
+   solvers must pass the kilo-grid gate of ``benchmarks/bench_des.py``,
+   and the solver's compiled program must hold the Pallas kernel as a TPU
+   custom call (compiled, not interpreted).
 3. Serving: ``repro.launch.serve.build_cluster("qwen2.5-3b", smoke=False,
    mode="miku")``, the path of ``python -m repro.launch.serve --arch
    qwen2.5-3b --full --mode miku``.  The host engine's weights must sit in
@@ -33,6 +33,7 @@ import json
 import os
 import sys
 import time
+from unittest import mock
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 for _p in (ROOT, os.path.join(ROOT, "src")):
@@ -94,33 +95,27 @@ def lane_phase(overrides=None) -> dict:
     from repro.scenarios import run_scenario
 
     tables, out = {}, {}
-    prev = os.environ.get("REPRO_BATCH_BACKEND")
-    try:
-        for backend in ("pallas", "numpy"):
-            os.environ["REPRO_BATCH_BACKEND"] = backend
-            walls = []
+    for backend in ("pallas", "numpy"):
+        fused = backend == "pallas"
+        walls = []
+        with mock.patch.object(kernel, "uses_fused_solver", lambda: fused):
             for _ in range(2):
                 t0 = time.perf_counter()
                 table = run_scenario("corun_sweep_1k", overrides,
                                      lane="batched")
                 walls.append(time.perf_counter() - t0)
-            meta = table.meta
-            print(f"[lane] {backend}: {len(table.rows)} cells, host wall s "
-                  f"cold {walls[0]} warm {walls[1]}")
-            print(f"[lane] {backend}: batched_jobs {meta['batched_jobs']} "
-                  f"scalar_fallback_jobs {meta['scalar_fallback_jobs']} "
-                  f"fallback_reason_counts "
-                  f"{json.dumps(meta['fallback_reason_counts'])}")
-            check(meta["scalar_fallback_jobs"] == 0,
-                  f"{backend}: cells fell back to the scalar DES: "
-                  f"{meta['fallback_reason_counts']}")
-            tables[backend] = table
-            out[f"{backend}_wall_s"] = walls
-    finally:
-        if prev is None:
-            os.environ.pop("REPRO_BATCH_BACKEND", None)
-        else:
-            os.environ["REPRO_BATCH_BACKEND"] = prev
+        meta = table.meta
+        print(f"[lane] {backend}: {len(table.rows)} cells, host wall s "
+              f"cold {walls[0]} warm {walls[1]}")
+        print(f"[lane] {backend}: batched_jobs {meta['batched_jobs']} "
+              f"scalar_fallback_jobs {meta['scalar_fallback_jobs']} "
+              f"fallback_reason_counts "
+              f"{json.dumps(meta['fallback_reason_counts'])}")
+        check(meta["scalar_fallback_jobs"] == 0,
+              f"{backend}: cells fell back to the scalar DES: "
+              f"{meta['fallback_reason_counts']}")
+        tables[backend] = table
+        out[f"{backend}_wall_s"] = walls
 
     def cells(table):
         return [(r["restricted_windows"] > 0,

@@ -22,10 +22,11 @@ station-service computation instead:
   reproduced exactly, including the DES's float-accumulated event times,
   so bandwidth and completed counts are **bit-identical** to the scalar
   lane.
-* :mod:`~repro.memsim.batched.kernel` — the per-window equilibrium solver:
-  a numpy bisection by default, or a Pallas kernel when
-  ``REPRO_BATCH_BACKEND=pallas`` (``jax.pallas``; compiled on the TPU,
-  interpreted on the CPU backend).
+* :mod:`~repro.memsim.batched.kernel` — the per-window equilibrium solver,
+  picked by the platform (:func:`~repro.memsim.batched.kernel.
+  uses_fused_solver`): on the TPU one jit dispatch per window whose
+  global-λ bisection is a compiled Pallas kernel, elsewhere a numpy
+  bisection.
 
 Entry point: :func:`run_sweep_batched`, normally reached through
 ``run_sweep(jobs, lane="batched")`` / ``benchmarks/run.py --lane batched``.

@@ -226,9 +226,9 @@ def run_fluid(
     # ``_w_effmlp`` writes.  Without tiering they never change.
     tier_frac_live = group.tier_frac.copy()
     effmlp_live = group.effmlp.copy()
-    # Under the pallas backend the whole relaxation loop runs as one jit
-    # dispatch per window (kernel.fused_window_solve); a failure raises.
-    use_fused = kernel.backend() == "pallas"
+    # On the TPU the whole relaxation loop runs as one jit dispatch per
+    # window (kernel.fused_window_solve); a failure raises.
+    use_fused = kernel.uses_fused_solver()
 
     # Accumulators.
     bytes_w = np.zeros((C, W))

@@ -16,33 +16,29 @@ rate λ (the fluid image of the DES's round-robin core arbitration):
   FIFO admission ties every hungry workload to the same per-core share,
   which is the paper's unfair-queuing collapse in fluid form.
 
-``global_lambda`` has two backends: numpy (default) and a Pallas kernel
-(``REPRO_BATCH_BACKEND=pallas``) that runs the whole bisection on-device
-(``jax.lax.fori_loop`` inside one ``pl.pallas_call``; compiled for the TPU,
-interpreted on the CPU backend, refused anywhere else).  Both produce the
-same fixed point to float tolerance — ``tests/test_batched.py`` pins
-backend parity.  A failing Pallas backend raises: nothing falls back to
-numpy behind the caller's back.
+Both are numpy bisections.  The platform picks how a window is solved
+(:func:`uses_fused_solver`): on the TPU the fluid engine hands the
+*entire* per-window wait-relaxation loop (station scaling, the global-λ
+bisection as a Pallas kernel, queue-builder population accounting,
+Little's-law wait update — everything between routing setup and the
+control-window fire) to one jit-compiled function,
+:func:`fused_window_solve`, so a window costs one solver dispatch instead
+of ``n_outer`` python iterations of einsums.  That is what scales 1k+-cell
+grids: the python overhead per window becomes O(1) in cell count.
+Everywhere else the numpy loop runs; tests run the fused solver on the CPU
+backend, where its Pallas kernel is interpreted, and pin its parity with
+the numpy loop (``tests/test_batched.py``).  A failing fused solver
+raises: nothing falls back to numpy behind the caller's back.
 
-:func:`fused_window_solve` goes further: under the pallas backend the
-fluid engine hands the *entire* per-window wait-relaxation loop (station
-scaling, global-λ Pallas bisection, queue-builder population
-accounting, Little's-law wait update — everything between routing setup
-and the control-window fire) to one jit-compiled function, so a window
-costs one solver dispatch instead of ``n_outer`` python iterations of
-einsums.  That is what scales 1k+-cell grids: the python overhead per
-window becomes O(1) in cell count.  The window's ten input arrays travel
-as one packed ``(C, 3W + 3WS + 2S + 2)`` float32 buffer
-(:func:`pack_window`), unpacked on the device by static slices in a
-program of its own, and ``y``, ``Wq`` and ``λ`` return as one
-``(C, W + S + 1)`` array: one host-to-device and one device-to-host copy
-per window, since each transfer costs far more than its bytes.
+The window's ten input arrays travel as one packed
+``(C, 3W + 3WS + 2S + 2)`` float32 buffer (:func:`pack_window`), unpacked
+on the device by static slices in a program of its own, and ``y``, ``Wq``
+and ``λ`` return as one ``(C, W + S + 1)`` array: one host-to-device and
+one device-to-host copy per window, since each transfer costs far more
+than its bytes.
 """
 
 from __future__ import annotations
-
-import os
-from typing import Optional
 
 import numpy as np
 
@@ -54,9 +50,13 @@ _EPS = 1e-9
 _BIG = 1e30
 
 
-def backend() -> str:
-    """Solver backend from ``REPRO_BATCH_BACKEND`` (numpy | pallas)."""
-    return os.environ.get("REPRO_BATCH_BACKEND", "numpy").strip().lower()
+def uses_fused_solver() -> bool:
+    """Whether this process solves windows with :func:`fused_window_solve`:
+    exactly when JAX's default backend is the TPU.  The one place that
+    holds the choice; the fluid engine asks it at call time."""
+    import jax
+
+    return jax.default_backend() == "tpu"
 
 
 def station_lambdas(
@@ -103,30 +103,9 @@ def _population(lam, A, cap, y_sta, o_eff, R_tor, irq_cap):
     return y, np.where(clamped, qb_pop, unclamped_pop)
 
 
-def _global_lambda_numpy(A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap):
-    C = A.shape[0]
-    hi0 = (cap / np.maximum(A, 1e-12)).max(axis=1) + 1e-6
-    lo = np.zeros(C)
-    hi = hi0.copy()
-
-    def feasible(lam):
-        _, pop = _population(lam, A, cap, y_sta, o_eff, R_tor, irq_cap)
-        return pop.sum(axis=1) <= tor_cap + _EPS
-
-    feasible_at_cap = feasible(hi0)
-    for _ in range(_BISECT_ITERS):
-        mid = 0.5 * (lo + hi)
-        ok = feasible(mid)
-        lo = np.where(ok, mid, lo)
-        hi = np.where(ok, hi, mid)
-    return np.where(feasible_at_cap, np.inf, lo)
-
-
-_pallas_solver = None
-
-
 def interpret_mode(platform: str) -> bool:
-    """Whether Pallas kernels run interpreted on ``platform``.
+    """Whether the fused solver's Pallas kernel runs interpreted on
+    ``platform``.
 
     Only the CPU backend interprets (tests and host rehearsals); the TPU
     compiles the kernel.  Any other platform has no kernel to run."""
@@ -135,7 +114,7 @@ def interpret_mode(platform: str) -> bool:
     if platform == "tpu":
         return False
     raise RuntimeError(
-        f"the Pallas solver runs on 'tpu' (compiled) or 'cpu' "
+        f"the fused solver runs on 'tpu' (compiled) or 'cpu' "
         f"(interpreted), not on {platform!r}"
     )
 
@@ -147,8 +126,8 @@ def _default_interpret() -> bool:
 
 
 def _glam_kernel(jax, jnp):
-    """The global-λ bisection as a Pallas kernel body (shared by the
-    standalone :func:`global_lambda` backend and the fused window solver)."""
+    """The global-λ bisection as a Pallas kernel body: the device twin of
+    :func:`global_lambda`, called inside the fused window solver."""
 
     def kernel(a_ref, cap_ref, ysta_ref, oeff_ref, rtor_ref, tor_ref,
                irq_ref, hi_ref, out_ref):
@@ -185,40 +164,6 @@ def _glam_kernel(jax, jnp):
     return kernel
 
 
-def _build_pallas_solver(interpret: bool):
-    """Compile the bisection as one Pallas kernel."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    kernel = _glam_kernel(jax, jnp)
-
-    @jax.jit
-    def solve(A, cap, y_sta, o_eff, r_tor, tor, irq, hi0):
-        return pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct(hi0.shape, jnp.float32),
-            interpret=interpret,
-        )(A, cap, y_sta, o_eff, r_tor, tor, irq, hi0)
-
-    return solve
-
-
-def _global_lambda_pallas(A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap):
-    global _pallas_solver
-    import jax.numpy as jnp
-
-    if _pallas_solver is None:
-        _pallas_solver = _build_pallas_solver(_default_interpret())
-    f32 = lambda x: jnp.asarray(np.minimum(x, _BIG), jnp.float32)  # noqa: E731
-    hi0 = (np.minimum(cap, _BIG) / np.maximum(A, 1e-12)).max(axis=1) + 1e-6
-    lam = _pallas_solver(
-        f32(A), f32(cap), f32(y_sta), f32(o_eff), f32(R_tor),
-        f32(tor_cap[:, None]), f32(irq_cap[:, None]), f32(hi0[:, None]),
-    )
-    return np.asarray(lam, dtype=np.float64)[:, 0]
-
-
 def global_lambda(
     A: np.ndarray,
     cap: np.ndarray,
@@ -227,7 +172,6 @@ def global_lambda(
     R_tor: np.ndarray,
     tor_cap: np.ndarray,
     irq_cap: np.ndarray,
-    force_backend: Optional[str] = None,
 ) -> np.ndarray:
     """Max common per-core rate per cell under the ToR population bound.
 
@@ -236,13 +180,22 @@ def global_lambda(
     bound; ``R_tor`` the per-insert ToR residency; ``irq_cap`` the staging
     queue each queue-builder's MLP partly parks in.  Returns ``(C,)`` λ,
     ``+inf`` where the ToR never fills."""
-    if (force_backend or backend()) == "pallas":
-        return _global_lambda_pallas(
-            A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap
-        )
-    return _global_lambda_numpy(
-        A, cap, y_sta, o_eff, R_tor, tor_cap, irq_cap
-    )
+    C = A.shape[0]
+    hi0 = (cap / np.maximum(A, 1e-12)).max(axis=1) + 1e-6
+    lo = np.zeros(C)
+    hi = hi0.copy()
+
+    def feasible(lam):
+        _, pop = _population(lam, A, cap, y_sta, o_eff, R_tor, irq_cap)
+        return pop.sum(axis=1) <= tor_cap + _EPS
+
+    feasible_at_cap = feasible(hi0)
+    for _ in range(_BISECT_ITERS):
+        mid = 0.5 * (lo + hi)
+        ok = feasible(mid)
+        lo = np.where(ok, mid, lo)
+        hi = np.where(ok, hi, mid)
+    return np.where(feasible_at_cap, np.inf, lo)
 
 
 _fused_solvers: dict = {}

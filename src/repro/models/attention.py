@@ -10,11 +10,13 @@ One implementation serves every assigned attention arch:
     >= S is full causal attention.
   * Logit soft-capping (gemma2).
   * Serving: ``attend_cached`` runs one-token decode against a [B, S_max]
-    cache updated in place (dynamic_update_slice), masked by current length.
+    cache updated in place (dynamic_update_slice), masked by current length;
+    ``attend_cached_stacked`` does the same against one layer of a cache
+    that stacks every attention layer of a hybrid stack.
 
-The flash-decode Pallas kernel (:mod:`repro.kernels`) replaces the cached
-path's einsums on TPU; this module is the lowering-friendly jnp baseline and
-the oracle's building block.
+Every path is the jnp einsums of this module, on every platform: XLA
+compiles them for the TPU as it does for the CPU, and no hand-written
+kernel takes their place.
 """
 
 from __future__ import annotations
@@ -117,7 +119,7 @@ def _grouped_values(probs: jax.Array, v: jax.Array) -> jax.Array:
 
 #: Above this sequence length, attend_full processes queries in row blocks
 #: of this size, bounding live score buffers to [B, H, Q_BLOCK, S] — the
-#: jnp flash-attention analogue (and the structure the Pallas kernel tiles).
+#: jnp flash-attention analogue.
 Q_BLOCK = 1024
 
 

@@ -2,9 +2,9 @@
 
 The chunked SSD algorithm: split the sequence into chunks of Q tokens; within
 a chunk the recurrence collapses to an attention-like quadratic contraction,
-across chunks a small [H, P, N] state is carried by a scan.  This is both
-the jnp baseline (lowering-friendly: one lax.scan over chunks nested inside
-the layer scan) and the oracle for the Pallas ``ssd_scan`` kernel.
+across chunks a small [H, P, N] state is carried by a scan.  It is written
+in jnp and runs as such on every platform (lowering-friendly: one lax.scan
+over chunks nested inside the layer scan).
 
 Decode is the pure recurrence: O(1) state per token, which is why the
 long_500k shape runs here.  In a hybrid stack (granite-4.0-h) the serving
@@ -127,8 +127,7 @@ def ssd_chunked(
 ) -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD scan: lax.scan over chunks carrying the [B,H,P,N] state,
     with the quadratic intra-chunk term computed *inside* the scan body so
-    peak temporaries are per-chunk ([B,Q,Q,H]) — the same blocking the
-    Pallas ``ssd_scan`` kernel tiles into VMEM.
+    peak temporaries are per-chunk ([B,Q,Q,H]).
 
     Returns (y [B,S,H,P], final_state [B,H,P,N]).
     """

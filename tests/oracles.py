@@ -1,8 +1,8 @@
-"""Pure-jnp oracles for the Pallas kernels.
+"""Pure-jnp oracles for the models' decode attention and SSD scan.
 
 These are deliberately *naive* implementations (full-materialization
-attention; token-by-token SSD recurrence) — independent of both the kernels
-and the blocked model code, so kernel bugs cannot hide behind shared logic.
+attention; token-by-token SSD recurrence) — independent of the blocked
+model code, so its bugs cannot hide behind shared logic.
 """
 
 from __future__ import annotations

@@ -53,17 +53,6 @@ def test_fused_window_solver_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in solver.lower(*args).compile().as_text()
 
 
-def test_global_lambda_kernel_compiles_for_v5e(one_chip):
-    solve = kernel._build_pallas_solver(interpret=False)
-    C, W = 1024, 4
-
-    def f32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-
-    args = [f32(C, W)] * 5 + [f32(C, 1)] * 3
-    assert "tpu_custom_call" in solve.lower(*args).compile().as_text()
-
-
 def _on(sharding, tree):
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),

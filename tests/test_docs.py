@@ -122,7 +122,7 @@ def test_examples_index_covers_all_demos():
                       "t_slow_raw", "class_counts", "tiering",
                       "queue_depth", "arrival-conservation")),
     ("decision-laws.md", ("TierDecisions", "VectorMikuLadder",
-                          "REPRO_BATCH_BACKEND", "fallback")),
+                          "uses_fused_solver", "fallback")),
     ("workloads.md", ("ArrivalSpec", "poisson", "zipf", "bursty",
                       "flash_crowd", "trace", "queue_limit", "slo_knee",
                       "REPRO_REGEN")),

@@ -64,10 +64,13 @@ def test_figure_harness_exits_nonzero_on_an_error_row(monkeypatch, capsys):
 def test_device_modules_stay_in_the_parent(monkeypatch):
     import benchmarks.run as harness
 
+    from repro.memsim.batched import kernel
+
     monkeypatch.delenv("REPRO_SWEEP_LANE", raising=False)
-    monkeypatch.delenv("REPRO_BATCH_BACKEND", raising=False)
+    monkeypatch.setattr(kernel, "uses_fused_solver", lambda: True)
     assert harness._uses_device("fig11_llm")
     assert not harness._uses_device("fig5_corun")
     monkeypatch.setenv("REPRO_SWEEP_LANE", "batched")
-    monkeypatch.setenv("REPRO_BATCH_BACKEND", "pallas")
     assert harness._uses_device("fig5_corun")
+    monkeypatch.setattr(kernel, "uses_fused_solver", lambda: False)
+    assert not harness._uses_device("fig5_corun")

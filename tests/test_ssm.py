@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ref import ssd_scan_ref
+from oracles import ssd_scan_ref
 from repro.models.ssm import (
     init_ssm_state,
     ssd_chunked,
