@@ -9,10 +9,10 @@ One implementation serves every assigned attention arch:
     SWA/full (hymba, h2o-danube) without breaking layer-stacking.  A window
     >= S is full causal attention.
   * Logit soft-capping (gemma2).
-  * Serving: ``attend_cached`` runs one-token decode against a [B, S_max]
-    cache updated in place (dynamic_update_slice), masked by current length;
-    ``attend_cached_stacked`` does the same against one layer of a cache
-    that stacks every attention layer of a hybrid stack.
+  * Serving: ``attend_cached_stacked`` runs one-token decode against one
+    layer of a cache that stacks every attention layer of the model,
+    writing each slot's new row in place and masking by its length (and
+    the layer's window); every stack's decode carries that cache.
 
 Every path is the jnp einsums of this module, on every platform: XLA
 compiles them for the TPU as it does for the CPU, and no hand-written
@@ -246,65 +246,6 @@ def project_memory_kv(params: Params, memory: jax.Array) -> Tuple[jax.Array, jax
 # ---------------------------------------------------------------------------
 
 
-def init_kv_cache(
-    batch: int, max_len: int, n_kv: int, head_dim: int, dtype
-) -> Dict[str, jax.Array]:
-    return {
-        "k": jnp.zeros((batch, max_len, n_kv, head_dim), dtype),
-        "v": jnp.zeros((batch, max_len, n_kv, head_dim), dtype),
-    }
-
-
-KV_CACHE_AXES = {"k": ("batch", "kv_seq", "kv_heads", "head_dim"),
-                 "v": ("batch", "kv_seq", "kv_heads", "head_dim")}
-
-
-def attend_cached(
-    params: Params,
-    x: jax.Array,
-    cache: Dict[str, jax.Array],
-    length: jax.Array,
-    *,
-    rope_theta: Optional[float],
-    window: jax.Array,
-    softcap_value: Optional[float] = None,
-    query_scale: Optional[float] = None,
-    update_cache: bool = True,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode.  x: [B, 1, D]; cache k/v: [B, S_max, Hkv, Dh];
-    ``length`` [B] or scalar = tokens already in cache (new token lands at
-    index ``length``).  Returns ([B, 1, D], updated cache)."""
-    b = x.shape[0]
-    positions = jnp.broadcast_to(jnp.atleast_1d(length), (b,))[:, None]  # [B,1]
-    q, k_new, v_new = project_qkv(params, x, positions, rope_theta=rope_theta)
-    if update_cache:
-        idx = jnp.broadcast_to(jnp.atleast_1d(length), (b,))
-
-        def upd(buf, new):
-            def one(buf_b, new_b, i):
-                return jax.lax.dynamic_update_slice_in_dim(buf_b, new_b, i, axis=0)
-
-            return jax.vmap(one)(buf, new, idx)
-
-        cache = {"k": upd(cache["k"], k_new), "v": upd(cache["v"], v_new)}
-        k, v = cache["k"], cache["v"]
-    else:
-        k, v = cache["k"], cache["v"]
-    dh = q.shape[-1]
-    scale = query_scale if query_scale is not None else dh**-0.5
-    scores = _grouped_scores(q * scale, k)  # [B,Hq,1,S_max]
-    if softcap_value is not None:
-        scores = softcap_value * jnp.tanh(scores / softcap_value)
-    t = jnp.arange(k.shape[1])[None, :]  # [1,S_max]
-    cur = positions  # [B,1]
-    valid = (t <= cur) & (cur - t < window)
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-    out = _grouped_values(probs, v)  # [B,1,Hq,Dh]
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return y, cache
-
-
 def attend_cached_stacked(
     params: Params,
     x: jax.Array,
@@ -313,12 +254,17 @@ def attend_cached_stacked(
     length: jax.Array,
     *,
     rope_theta: Optional[float],
+    window: Optional[jax.Array] = None,
+    softcap_value: Optional[float] = None,
     query_scale: Optional[float] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token decode against the K/V of every attention layer of a
-    stack, ``cache`` k/v: [L_attn, B, S_max, Hkv, Dh].  The new row of each
+    stack, ``cache`` k/v: [L, B, S_max, Hkv, Dh].  The new row of each
     slot is written in place at ``[layer, slot, length[slot]]``, and the
-    token attends to the positions up to it.  Returns ([B, 1, D], cache)."""
+    token attends to the positions up to it: to the last ``window`` of
+    them when a window (a traced scalar) is given, with its scores
+    soft-capped at ``softcap_value`` when one is.  Returns ([B, 1, D],
+    cache)."""
     b = x.shape[0]
     positions = length[:, None]  # [B,1]
     q, k_new, v_new = project_qkv(params, x, positions, rope_theta=rope_theta)
@@ -334,7 +280,12 @@ def attend_cached_stacked(
     dh = q.shape[-1]
     scale = query_scale if query_scale is not None else dh**-0.5
     scores = _grouped_scores(q * scale, k)  # [B,Hq,1,S_max]
-    valid = jnp.arange(k.shape[1])[None, :] <= positions  # [B,S_max]
+    if softcap_value is not None:
+        scores = softcap_value * jnp.tanh(scores / softcap_value)
+    t = jnp.arange(k.shape[1])[None, :]  # [1,S_max]
+    valid = t <= positions  # [B,S_max]
+    if window is not None:
+        valid = valid & (positions - t < window)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
     out = _grouped_values(probs, v)  # [B,1,Hq,Dh]

@@ -699,7 +699,7 @@ class TransformerLM:
         )
 
     def _pair_view(self, tree):
-        """[L, ...] -> [L/2, 2, ...] for pair-scanned stacks."""
+        """[L, ...] -> [L/2, 2, ...] for a pair-scanned prefill."""
         if tree is None:
             return None
         ns = self.cfg.n_scan
@@ -712,26 +712,37 @@ class TransformerLM:
             lambda x: x.reshape((x.shape[0] * 2,) + x.shape[2:]), tree
         )
 
+    def _ssm_decode(self, layer: Params, h: jax.Array, st, i,
+                    eps: float = 1e-6):
+        """One-token SSM step of layer ``i`` of the stacked SSM state
+        ``st``, which it updates in place.  Returns (out, st)."""
+        mine = {k: jax.lax.dynamic_index_in_dim(st[k], i, keepdims=False)
+                for k in st}
+        out, new = ssm_lib.ssm_step(layer["ssm"], h, mine, self.cfg.ssm_dims,
+                                    eps)
+        return out, {k: jax.lax.dynamic_update_index_in_dim(
+            st[k], new[k].astype(st[k].dtype), i, 0) for k in st}
+
     def _sub_decode(self, layer: Params, x: jax.Array, kv, ssm_state, cross,
-                    window, length):
-        """One (sub-)layer, one-token decode.  Returns (x, new_kv, new_ssm)."""
+                    window, length, i):
+        """One (sub-)layer, one-token decode: layer ``i`` of the stacked
+        K/V and SSM state, both updated in place.  Returns (x, kv, ssm)."""
         cfg = self.cfg
         x = constrain(x, ("batch", "seq", "embed_act"))
-        new_kv = new_ssm = None
         if "attn" not in layer:
             h = self._norm(x, layer["pre_ssm_norm"])
-            y, new_ssm = ssm_lib.ssm_step(layer["ssm"], h, ssm_state, cfg.ssm_dims)
+            y, ssm_state = self._ssm_decode(layer, h, ssm_state, i)
             x = x + y
             x, _ = self._ffn_apply(layer, x)
-            return x, new_kv, new_ssm
+            return x, kv, ssm_state
         h = self._norm(x, layer["pre_attn_norm"])
-        a, new_kv = attn.attend_cached(
-            layer["attn"], h, kv, length, rope_theta=cfg.rope_theta,
+        a, kv = attn.attend_cached_stacked(
+            layer["attn"], h, kv, i, length, rope_theta=cfg.rope_theta,
             window=window, softcap_value=cfg.attn_softcap,
             query_scale=cfg.query_scale,
         )
         if "ssm" in layer:
-            s2, new_ssm = ssm_lib.ssm_step(layer["ssm"], h, ssm_state, cfg.ssm_dims)
+            s2, ssm_state = self._ssm_decode(layer, h, ssm_state, i)
             a = 0.5 * (a + s2)
         if cfg.use_post_norms:
             a = self._norm(a, layer["post_attn_norm"])
@@ -740,7 +751,7 @@ class TransformerLM:
             h = self._norm(x, layer["pre_cross_norm"])
             x = x + attn.attend_cross(layer["cross"], h, cross["k"], cross["v"])
         x, _ = self._ffn_apply(layer, x)
-        return x, new_kv, new_ssm
+        return x, kv, ssm_state
 
     def decode_step(
         self,
@@ -748,68 +759,42 @@ class TransformerLM:
         state: DecodeState,
         token: jax.Array,  # [B] int32
     ) -> Tuple[jax.Array, DecodeState]:
-        """One decode step: (logits [B, V], new state)."""
+        """One decode step: (logits [B, V], new state).  The stacked K/V
+        and SSM state ride the layer scan's carry, and each layer writes
+        its one new row in place, so a donated state is updated where it
+        lies; cross-attention K/V is read only, scanned per layer."""
         cfg = self.cfg
         if cfg.interleaved:
             return self._il_decode_step(params, state, token)
         x = embed_lookup(params["embed"], token[:, None])  # [B,1,D]
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
-        windows = cfg.window_sizes()
         length = state.length
-
-        inp: Dict[str, Any] = {"layer": params["layers"], "window": windows}
-        if state.kv is not None:
-            inp["kv"] = self._pair_view(state.kv) if cfg.paired else state.kv
-        if state.ssm is not None:
-            inp["ssm"] = state.ssm
+        xs: Dict[str, Any] = {"layer": params["layers"],
+                              "window": cfg.window_sizes(),
+                              "i": jnp.arange(cfg.n_scan)}
         if state.cross_kv is not None:
-            inp["cross"] = (self._pair_view(state.cross_kv) if cfg.paired
-                            else state.cross_kv)
+            xs["cross"] = state.cross_kv
 
-        def body(carry, inp1):
-            x1 = carry
-            layer, window = inp1["layer"], inp1["window"]
-            outs: Dict[str, Any] = {}
-            if cfg.paired:
-                kv = inp1["kv"]
-                cross = inp1.get("cross")
-                x1, k0, _ = self._sub_decode(
-                    layer["dense"], x1,
-                    jax.tree.map(lambda t: t[0], kv),
-                    None, None if cross is None else
-                    jax.tree.map(lambda t: t[0], cross),
-                    window[0], length,
-                )
-                x1, k1, _ = self._sub_decode(
-                    layer["moe"], x1, jax.tree.map(lambda t: t[1], kv),
-                    None, None, window[1], length,
-                )
-                outs["kv"] = jax.tree.map(lambda a, b: jnp.stack([a, b]), k0, k1)
+        def body(carry, inp):
+            x1, kv, ssm = carry
+            layer, window, i = inp["layer"], inp["window"], inp["i"]
+            cross = inp.get("cross")
+            if cfg.paired:  # layers 2i (dense) and 2i+1 (MoE) of one stack
+                x1, kv, _ = self._sub_decode(layer["dense"], x1, kv, None,
+                                             cross, window[0], length, 2 * i)
+                x1, kv, _ = self._sub_decode(layer["moe"], x1, kv, None, None,
+                                             window[1], length, 2 * i + 1)
             else:
-                x1, new_kv, new_ssm = self._sub_decode(
-                    layer, x1, inp1.get("kv"), inp1.get("ssm"),
-                    inp1.get("cross"), window, length,
-                )
-                if new_kv is not None:
-                    outs["kv"] = new_kv
-                if new_ssm is not None:
-                    outs["ssm"] = new_ssm
-            return x1, outs
+                x1, kv, ssm = self._sub_decode(layer, x1, kv, ssm, cross,
+                                               window, length, i)
+            return (x1, kv, ssm), None
 
-        x, outs = jax.lax.scan(body, x, inp)
+        (x, kv, ssm), _ = jax.lax.scan(body, (x, state.kv, state.ssm), xs)
         x = self._norm(x, params["final_norm"])
         logits = self._logits(params, x)[:, 0, :]
-        new_kv = outs.get("kv")
-        if new_kv is not None and cfg.paired:
-            new_kv = self._pair_unview(new_kv)
-        new_state = DecodeState(
-            kv=new_kv if new_kv is not None else state.kv,
-            ssm=outs.get("ssm", state.ssm),
-            cross_kv=state.cross_kv,
-            length=length + 1,
-        )
-        return logits, new_state
+        return logits, DecodeState(kv=kv, ssm=ssm, cross_kv=state.cross_kv,
+                                   length=length + 1)
 
     def _sub_prefill(self, layer: Params, x: jax.Array, positions, window,
                      kv, cross):
@@ -1043,12 +1028,7 @@ class TransformerLM:
         def mixer(kind, lp, h, st, i):
             if kind == "mamba":
                 with jax.named_scope("ssm"):
-                    mine = {k: jax.lax.dynamic_index_in_dim(
-                        st[k], i, keepdims=False) for k in st}
-                    out, new = ssm_lib.ssm_step(lp["ssm"], h, mine,
-                                                cfg.ssm_dims, cfg.norm_eps)
-                    st = {k: jax.lax.dynamic_update_index_in_dim(
-                        st[k], new[k].astype(st[k].dtype), i, 0) for k in st}
+                    out, st = self._ssm_decode(lp, h, st, i, cfg.norm_eps)
             else:
                 with jax.named_scope("attn"):
                     out, st = attn.attend_cached_stacked(

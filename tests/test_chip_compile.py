@@ -115,3 +115,22 @@ def test_granite_share_decode_fits_one_v5e(one_chip):
     assert mem.alias_size_in_bytes >= state_bytes - 2 ** 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         <= V5E_HBM_BYTES
+
+
+def test_qwen_decode_updates_its_cache_in_place(one_chip):
+    """The chat cells' decode (qwen2.5-3b, 16 slots of 2560 positions)
+    carries its stacked K/V through the layer scan and writes one row per
+    layer where it lies: the donated state is aliased whole, and the
+    program holds no second copy of the cache (1.51 GB) as temp."""
+    model = TransformerLM(get_arch("qwen2.5-3b").config)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))[0]))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: model.init_decode_state(16, 2560)))
+    token = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    mem = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, state, token).compile().memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes - 2 ** 20
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20

@@ -1,8 +1,9 @@
 """The decode math the chip runs, against the naive oracles of
-``tests/oracles.py``: cached attention (``attention.attend_cached`` for a
-uniform stack, ``attention.attend_cached_stacked`` for granite's hybrid
-stack) and the chunked SSD scan (``ssm.ssd_chunked``), swept over shapes
-and dtypes."""
+``tests/oracles.py``: cached attention (``attention.attend_cached_stacked``,
+which every stack's decode runs: with a per-layer window and soft-cap as a
+uniform stack passes them, and without, as granite's hybrid stack calls
+it) and the chunked SSD scan (``ssm.ssd_chunked``), swept over shapes and
+dtypes."""
 
 from functools import partial
 
@@ -14,11 +15,7 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from oracles import decode_attention_ref, ssd_scan_ref
-from repro.models.attention import (
-    attend_cached,
-    attend_cached_stacked,
-    project_qkv,
-)
+from repro.models.attention import attend_cached_stacked, project_qkv
 from repro.models.ssm import ssd_chunked
 
 ROPE_THETA = 10_000.0
@@ -67,8 +64,17 @@ def _oracle(params, x, k, v, length, window=1 << 30, softcap=None):
     return out.reshape(b, 1, hq * dh), k, v
 
 
-_attend = jax.jit(partial(attend_cached, rope_theta=ROPE_THETA),
-                  static_argnames=("window", "softcap_value"))
+@partial(jax.jit, static_argnames=("softcap_value",))
+def _attend(params, x, cache, length, window, softcap_value=None):
+    """A uniform stack's decode attention, on a one-layer stacked cache:
+    the window is traced, as the layer scan passes it."""
+    y, new = attend_cached_stacked(
+        params, x, jax.tree.map(lambda c: c[None], cache), jnp.int32(0),
+        length, rope_theta=ROPE_THETA, window=window,
+        softcap_value=softcap_value)
+    return y, jax.tree.map(lambda c: c[0], new)
+
+
 _attend_stacked = jax.jit(partial(attend_cached_stacked,
                                   rope_theta=ROPE_THETA))
 
@@ -92,7 +98,7 @@ def _close(got, want, tol):
 )
 def test_decode_attention_sweep(dtype, b, hq, hkv, dh, s):
     params, x, cache, length = _decode_case(0, b, hq, hkv, dh, s, dtype)
-    y, new = _attend(params, x, cache, length, window=1 << 30)
+    y, new = _attend(params, x, cache, length, jnp.int32(1 << 30))
     ref, k, v = _oracle(params, x, cache["k"], cache["v"], length)
     _close(y, ref, 2e-2 if dtype == jnp.bfloat16 else 1e-5)
     np.testing.assert_array_equal(new["k"], k)
@@ -105,7 +111,7 @@ def test_decode_attention_window_softcap(window, softcap):
     b, hq, hkv, dh, s = 2, 8, 4, 64, 256
     params, x, cache, _ = _decode_case(1, b, hq, hkv, dh, s, jnp.float32)
     length = jnp.array([s - 1, s // 3 - 1], jnp.int32)
-    y, _ = _attend(params, x, cache, length, window=window,
+    y, _ = _attend(params, x, cache, length, jnp.int32(window),
                    softcap_value=softcap)
     ref, _, _ = _oracle(params, x, cache["k"], cache["v"], length,
                         window=window, softcap=softcap)
@@ -124,7 +130,7 @@ def test_decode_attention_property(b, hkv, group, s_blocks):
     hq = hkv * group
     params, x, cache, length = _decode_case(b * 131 + hq, b, hq, hkv, dh, s,
                                             jnp.float32)
-    y, _ = _attend(params, x, cache, length, window=1 << 30)
+    y, _ = _attend(params, x, cache, length, jnp.int32(1 << 30))
     ref, _, _ = _oracle(params, x, cache["k"], cache["v"], length)
     _close(y, ref, 2e-5)
 

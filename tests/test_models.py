@@ -96,20 +96,35 @@ def test_prefill_matches_forward(arch_id):
     [
         "qwen2.5-3b",
         "mamba2-2.7b",
+        "gemma2-27b",  # local/global windows, soft-capped scores
+        "llama4-maverick-400b-a17b",  # dense/MoE pairs in one stack
         pytest.param("hymba-1.5b", marks=pytest.mark.slow),
+        pytest.param("whisper-large-v3", marks=pytest.mark.slow),
     ],
 )
 def test_decode_step_matches_forward(arch_id):
-    """prefill(t) + decode(token_t) == forward(t+1 tokens) last logits."""
+    """prefill(t) + decode(token_t) == forward(t+1 tokens) last logits.
+    Sliding windows are cut to 4 so that they mask part of the 9 tokens;
+    expert capacity holds every token, since the forward's capacity drops
+    have no counterpart in a one-token decode."""
     cfg = dataclasses.replace(get_arch(arch_id).smoke, dtype=jnp.float32)
+    if cfg.sliding_window:
+        cfg = dataclasses.replace(cfg, sliding_window=4)
+    if cfg.n_experts:
+        cfg = dataclasses.replace(cfg,
+                                  capacity_factor=cfg.n_experts / cfg.top_k)
     model = TransformerLM(cfg)
     params, _ = model.init(KEY)
     B, S = 1, 9
     tokens = jax.random.randint(KEY, (B, S), 1, cfg.vocab)
+    frames = None  # an encoder-decoder's cross-attention memory
+    if cfg.n_encoder_layers:
+        frames = jax.random.normal(KEY, (B, cfg.encoder_seq, cfg.d_model))
     state = model.init_decode_state(B, 32)
-    _, state = model.prefill(params, tokens[:, :-1], state)
+    _, state = model.prefill(params, tokens[:, :-1], state,
+                             frontend_embeds=frames)
     dec_logits, _ = model.decode_step(params, state, tokens[:, -1])
-    hidden, _ = model.forward(params, tokens)
+    hidden, _ = model.forward(params, tokens, frontend_embeds=frames)
     ref = model.logits(params, hidden)[:, -1, :]
     np.testing.assert_allclose(
         np.asarray(dec_logits), np.asarray(ref), atol=3e-3, rtol=3e-3
