@@ -50,6 +50,7 @@ ARCH_IDS: List[str] = [
     # the paper's own LLM-serving case-study model (§6, LLaMA 3.1 8B class):
     "llama31-8b",
     "granite-4.0-h-small",
+    "deepseek-v2-lite",
 ]
 
 _MODULES = {
@@ -65,6 +66,7 @@ _MODULES = {
     "mamba2-2.7b": "mamba2_2p7b",
     "llama31-8b": "llama31_8b",
     "granite-4.0-h-small": "granite_4h_small",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

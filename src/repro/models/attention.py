@@ -13,6 +13,12 @@ One implementation serves every assigned attention arch:
     layer of a cache that stacks every attention layer of the model,
     writing each slot's new row in place and masking by its length (and
     the layer's window); every stack's decode carries that cache.
+  * Latent attention (MLA, DeepSeek-V2): K and V of every head expand
+    from one shared latent per position, and only the normed latent and
+    one roped key shared by the heads are cached.  ``mla_full`` (prefill,
+    training) expands them; ``mla_cached`` (decode) absorbs each head's
+    key expansion into its query and its value expansion into its output,
+    so a step reads the latent cache as it lies.
 
 Every path is the jnp einsums of this module, on every platform: XLA
 compiles them for the TPU as it does for the CPU, and no hand-written
@@ -26,7 +32,14 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import Axes, Params, apply_rope, dense_init, rmsnorm
+from repro.models.layers import (
+    Axes,
+    Params,
+    apply_rope,
+    apply_rope_pairs,
+    dense_init,
+    rmsnorm,
+)
 
 NEG_INF = -2.3819763e38  # large negative for masking (bf16-safe)
 
@@ -290,3 +303,164 @@ def attend_cached_stacked(
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
     out = _grouped_values(probs, v)  # [B,1,Hq,Dh]
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), cache
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA)
+# ---------------------------------------------------------------------------
+
+
+def mla_init(
+    key,
+    d_model: int,
+    n_heads: int,
+    *,
+    kv_lora_rank: int,
+    nope_dim: int,
+    rope_dim: int,
+    v_dim: int,
+    dtype,
+    stacked: Optional[int] = None,
+) -> Tuple[Params, Axes]:
+    """DeepSeek-V2's attention with no query compression: ``wq``
+    (``q_proj``), ``wkv_a`` (``kv_a_proj_with_mqa``: the latent, then the
+    shared rope key), ``kv_norm`` (``kv_a_layernorm``), ``w_uk`` and
+    ``w_uv`` (``kv_b_proj``'s key and value halves, [r, H, dim]) and
+    ``wo``."""
+    kq, ka, kk, kv, ko = jax.random.split(key, 5)
+    lead = (stacked,) if stacked else ()
+    lead_ax = ("layers",) if stacked else ()
+    r = kv_lora_rank
+    params: Params = {
+        "wq": dense_init(kq, d_model,
+                         lead + (d_model, n_heads, nope_dim + rope_dim), dtype),
+        "wkv_a": dense_init(ka, d_model, lead + (d_model, r + rope_dim), dtype),
+        "kv_norm": jnp.zeros(lead + (r,), dtype),
+        "w_uk": dense_init(kk, r, lead + (r, n_heads, nope_dim), dtype),
+        "w_uv": dense_init(kv, r, lead + (r, n_heads, v_dim), dtype),
+        "wo": dense_init(ko, n_heads * v_dim, lead + (n_heads, v_dim, d_model),
+                         dtype),
+    }
+    axes: Axes = {
+        "wq": lead_ax + ("embed", "q_heads", "head_dim"),
+        "wkv_a": lead_ax + ("embed", "kv_lora"),
+        "kv_norm": lead_ax + ("kv_lora",),
+        "w_uk": lead_ax + ("kv_lora", "q_heads", "head_dim"),
+        "w_uv": lead_ax + ("kv_lora", "q_heads", "head_dim"),
+        "wo": lead_ax + ("q_heads", "head_dim", "embed"),
+    }
+    return params, axes
+
+
+def _mla_project(params: Params, x: jax.Array, positions: jax.Array, *,
+                 inv_freq, rope_scale: float, eps: float):
+    """x [B, S, D] -> roped query halves q_nope [B,S,H,n], q_pe [B,S,H,p],
+    the normed latent c [B,S,r] and the roped shared key k_pe [B,S,p]."""
+    r = params["w_uk"].shape[-3]
+    nope = params["w_uk"].shape[-1]
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    kv = jnp.einsum("bsd,dc->bsc", x, params["wkv_a"])
+    c = rmsnorm(kv[..., :r], params["kv_norm"], eps)
+    q_pe = apply_rope_pairs(q[..., nope:], positions, inv_freq, rope_scale)
+    k_pe = apply_rope_pairs(kv[..., r:], positions, inv_freq, rope_scale)
+    return q[..., :nope], q_pe, c, k_pe
+
+
+def mla_full(
+    params: Params,
+    x: jax.Array,
+    positions: jax.Array,
+    cache: Optional[Dict[str, jax.Array]] = None,
+    layer: Optional[jax.Array] = None,
+    *,
+    inv_freq,
+    rope_scale: float,
+    scale: float,
+    eps: float,
+    q_block: int = Q_BLOCK,
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """Causal MLA over a whole sequence in its expanded form: per-head K
+    (``c @ w_uk`` beside the shared rope key) and V (``c @ w_uv``), scores
+    scaled by ``scale``, queries in blocks of ``q_block`` rows.  With a
+    ``cache`` (c_kv [L, B, S_max, r], k_pe [L, B, S_max, p]) the latent
+    and rope key of every position are written into layer ``layer``.
+    Returns ([B, S, D], cache)."""
+    b, s, _ = x.shape
+    q_nope, q_pe, c, k_pe = _mla_project(
+        params, x, positions, inv_freq=inv_freq, rope_scale=rope_scale,
+        eps=eps)
+    if cache is not None:
+        cache = {n: jax.lax.dynamic_update_slice(
+            cache[n], t.astype(cache[n].dtype)[None], (layer, 0, 0, 0))
+            for n, t in (("c_kv", c), ("k_pe", k_pe))}
+    k_nope = jnp.einsum("bsr,rhn->bshn", c, params["w_uk"])
+    v = jnp.einsum("bsr,rhv->bshv", c, params["w_uv"])
+    q_nope, q_pe = q_nope * scale, q_pe * scale
+    tpos = positions[:, None, :]  # [B,1,T]
+
+    def core(qn, qp, qpos):
+        scores = (jnp.einsum("bqhn,bthn->bhqt", qn, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhp,btp->bhqt", qp, k_pe,
+                               preferred_element_type=jnp.float32))
+        mask = tpos <= qpos[:, :, None]  # [B,Q,T]
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhqt,bthv->bqhv", probs.astype(x.dtype), v)
+
+    if s <= q_block or s % q_block != 0:
+        out = core(q_nope, q_pe, positions)
+    else:
+        nb = s // q_block
+
+        def blocks(t):
+            return t.reshape((b, nb, q_block) + t.shape[2:]).swapaxes(0, 1)
+
+        out = jax.lax.map(jax.checkpoint(lambda a: core(*a)),
+                          (blocks(q_nope), blocks(q_pe), blocks(positions)))
+        out = out.swapaxes(0, 1).reshape((b, s) + out.shape[3:])
+    return jnp.einsum("bshv,hvd->bsd", out, params["wo"]), cache
+
+
+def mla_cached(
+    params: Params,
+    x: jax.Array,
+    cache: Dict[str, jax.Array],
+    layer: jax.Array,
+    length: jax.Array,
+    *,
+    inv_freq,
+    rope_scale: float,
+    scale: float,
+    eps: float,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token MLA decode in its absorbed form against layer ``layer``
+    of the stacked latent cache (c_kv [L, B, S_max, r], k_pe [L, B,
+    S_max, p]).  Each slot's latent and rope key are written in place at
+    ``[layer, slot, length[slot]]``; each head's query is mapped through
+    its ``w_uk`` into the latent (no merged weight is stored), scored
+    against the cached latents plus ``q_pe . k_pe``, the latents summed by
+    the softmax and mapped out through the head's ``w_uv``.  Returns
+    ([B, 1, D], cache)."""
+    b = x.shape[0]
+    positions = length[:, None]  # [B,1]
+    q_nope, q_pe, c, k_pe = _mla_project(
+        params, x, positions, inv_freq=inv_freq, rope_scale=rope_scale,
+        eps=eps)
+    slots = jnp.arange(b)
+    cache = {n: cache[n].at[layer, slots, length].set(
+        t[:, 0].astype(cache[n].dtype)) for n, t in (("c_kv", c),
+                                                     ("k_pe", k_pe))}
+    lat = jax.lax.dynamic_index_in_dim(cache["c_kv"], layer, keepdims=False)
+    rope = jax.lax.dynamic_index_in_dim(cache["k_pe"], layer, keepdims=False)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0] * scale, params["w_uk"])
+    scores = (jnp.einsum("bhr,btr->bht", q_lat, lat,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhp,btp->bht", q_pe[:, 0] * scale, rope,
+                           preferred_element_type=jnp.float32))
+    t = jnp.arange(lat.shape[1])[None, :]  # [1,S_max]
+    scores = jnp.where((t <= positions)[:, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    ctx = jnp.einsum("bht,btr->bhr", probs, lat)
+    out = jnp.einsum("bhr,rhv->bhv", ctx, params["w_uv"])
+    return jnp.einsum("bhv,hvd->bd", out, params["wo"])[:, None], cache

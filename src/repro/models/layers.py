@@ -7,10 +7,13 @@ Parameters are plain nested dicts of jnp arrays; every init function returns
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 Axes = Dict[str, Any]
@@ -82,6 +85,61 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's stretch of RoPE to ``factor`` times the context it was
+    trained on (Peng et al., arXiv:2309.00071), with DeepSeek's ``mscale``
+    pair."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_range(dim: int, theta: float, yarn: Yarn) -> Tuple[int, int]:
+    """The frequencies ``[low, high]`` over which YaRN ramps from keeping
+    them (below ``low``: ``beta_fast`` or more turns over the original
+    context) to dividing them by ``factor`` (above ``high``: fewer than
+    ``beta_slow`` turns)."""
+
+    def at(turns):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(at(yarn.beta_fast)), 0),
+            min(math.ceil(at(yarn.beta_slow)), dim - 1))
+
+
+def yarn_frequencies(dim: int, theta: float, yarn: Yarn) -> np.ndarray:
+    """YaRN's ``dim / 2`` inverse frequencies (float32)."""
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_range(dim, theta, yarn)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (base / yarn.factor * ramp + base * (1 - ramp)).astype(np.float32)
+
+
+def apply_rope_pairs(x: jax.Array, positions: jax.Array, inv_freq,
+                     scale: float) -> jax.Array:
+    """RoPE on interleaved pairs ``(2i, 2i+1)``, each turned by frequency
+    ``i`` (DeepSeek's layout).  x: [B, S, ..., D]; positions: [B, S];
+    cos and sin are multiplied by ``scale``."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    x0, x1 = xr[..., 0], xr[..., 1]
+    out = jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

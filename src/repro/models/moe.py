@@ -329,24 +329,33 @@ def held_moe_apply(
     top_k: int,
     held: Tuple[int, int],
     activation: str = "silu",
+    router: str = "topk_softmax",
 ) -> Tuple[jax.Array, jax.Array]:
     """x: [B, S, D] -> (this share's output [B, S, D], held experts that
     at least one token was routed to).
 
-    Granite's router: float32 logits over every expert, the ``top_k``
-    largest, and a softmax over those ``top_k`` logits alone.  Each held
-    expert runs on every token, weighted by that token's gate for it (0
-    where the token was routed elsewhere), so no token is dropped and a
-    token routed to experts held on other chips adds nothing here.  The
-    gate and up projections of all held experts are one matrix product,
-    and so are the down projections and their weighted sum."""
+    The router takes float32 logits over every expert.  ``topk_softmax``
+    (Granite): the ``top_k`` largest, and a softmax over those alone.
+    ``softmax_topk`` (DeepSeek-V2): a softmax over every expert, then the
+    ``top_k`` largest probabilities as they are.  Each held expert runs on every
+    token, weighted by that token's gate for it (0 where the token was
+    routed elsewhere), so no token is dropped and a token routed to
+    experts held on other chips adds nothing here.  The gate and up
+    projections of all held experts are one matrix product, and so are the
+    down projections and their weighted sum."""
     b, s, d = x.shape
     lo, hi = held
     xf = x.reshape(b * s, d)
     logits = jnp.einsum("td,de->te", xf, params["router"],
                         preferred_element_type=jnp.float32)
-    top_vals, top_idx = jax.lax.top_k(logits, top_k)
-    gates = jax.nn.softmax(top_vals, axis=-1)  # [T, k]
+    if router == "topk_softmax":
+        top_vals, top_idx = jax.lax.top_k(logits, top_k)
+        gates = jax.nn.softmax(top_vals, axis=-1)  # [T, k]
+    elif router == "softmax_topk":
+        gates, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                       top_k)
+    else:
+        raise ValueError(router)
     ids = lo + jnp.arange(hi - lo)
     weight = jnp.sum(jnp.where(top_idx[:, :, None] == ids, gates[:, :, None],
                                0.0), axis=1)  # [T, held]

@@ -19,6 +19,12 @@ per kind, and it scans over whole periods of the pattern (an inner scan
 per run of one kind), so its program is O(1) in depth too.  Its decode
 state holds K/V only for the attention layers and SSM state only for the
 Mamba layers, both carried through the scan and updated in place.
+
+A third kind, ``mla`` (DeepSeek-V2), is latent attention: its state is the
+latent cache (``c_kv`` and ``k_pe``) of every MLA layer.  Leading dense
+layers (``n_dense_lead``: the mixer, then a dense SwiGLU of ``d_ff_dense``)
+run once before the scan and fill the first layers of the same stacked
+state.
 """
 
 from __future__ import annotations
@@ -36,12 +42,17 @@ from repro.models import ssm as ssm_lib
 from repro.models.layers import (
     Axes,
     Params,
+    Yarn,
     embed_init,
     embed_lookup,
     layernorm,
+    mlp_apply,
+    mlp_init,
     rmsnorm,
     softcap,
     unembed,
+    yarn_frequencies,
+    yarn_mscale,
 )
 
 FULL_WINDOW = 1 << 30  # "window" larger than any sequence = dense attention
@@ -95,12 +106,26 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_expand: int = 2
     ssm_chunk: int = 128
-    # interleaved stacks (granite-4.0-h): each layer's kind, "mamba" or
-    # "attention", in order; the pattern repeats with some period
+    # interleaved stacks (granite-4.0-h, deepseek-v2): each layer's kind,
+    # "mamba", "attention" or "mla", in order; after the leading dense
+    # layers the pattern repeats with some period
     layer_types: Optional[Tuple[str, ...]] = None
+    #: leading layers of an interleaved stack whose FFN is a dense SwiGLU
+    #: of d_ff_dense (DeepSeek's first_k_dense_replace); all of one kind
+    n_dense_lead: int = 0
     #: the expert-parallel share this chip holds: experts [lo, hi) of
     #: n_experts (None: all); the router still routes over all of them
     experts_held: Optional[Tuple[int, int]] = None
+    #: the held-expert layer's router (``moe.held_moe_apply``):
+    #: "topk_softmax" (Granite) or "softmax_topk" (DeepSeek-V2)
+    router: str = "topk_softmax"
+    # latent attention (MLA): the cached latent's width, each head's
+    # query/key width without and with RoPE, its value width, and YaRN
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Optional[Yarn] = None
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0  # logits are divided by it
@@ -140,9 +165,14 @@ class ModelConfig:
         return self.experts_held or (0, self.n_experts)
 
     @property
+    def scanned_types(self) -> Tuple[str, ...]:
+        """``layer_types`` after the leading dense layers."""
+        return self.layer_types[self.n_dense_lead:]
+
+    @property
     def period(self) -> int:
-        """Layers in one repeat of ``layer_types``."""
-        t = self.layer_types
+        """Layers in one repeat of ``scanned_types``."""
+        t = self.scanned_types
         for p in range(1, len(t) + 1):
             if len(t) % p == 0 and t == t[:p] * (len(t) // p):
                 return p
@@ -151,12 +181,30 @@ class ModelConfig:
     def kind_count(self, kind: str) -> int:
         return sum(k == kind for k in self.layer_types)
 
+    @property
+    def kv_kind(self) -> str:
+        """The kind whose state ``DecodeState.kv`` holds."""
+        return "mla" if "mla" in self.layer_types else "attention"
+
+    def mla_args(self) -> Dict[str, Any]:
+        """Constants of the MLA layers, as DeepSeek's code sets them:
+        YaRN's inverse frequencies, cos and sin times ``mscale(factor,
+        mscale) / mscale(factor, mscale_all_dim)``, and the softmax scale
+        ``(nope + rope)^-0.5`` times ``mscale(factor, mscale_all_dim)^2``."""
+        y, rope = self.yarn, self.qk_rope_head_dim
+        spread = yarn_mscale(y.factor, y.mscale_all_dim)
+        return dict(
+            inv_freq=yarn_frequencies(rope, self.rope_theta, y),
+            rope_scale=yarn_mscale(y.factor, y.mscale) / spread,
+            scale=(self.qk_nope_head_dim + rope) ** -0.5 * spread ** 2,
+            eps=self.norm_eps)
+
     def segments(self) -> Tuple[Tuple[str, int, int, int], ...]:
         """Runs of one kind in a period: (kind, index of the run's first
         layer among the period's layers of that kind, run length, its
         position in the period)."""
         out, seen = [], {}
-        kinds = self.layer_types[:self.period]
+        kinds = self.scanned_types[:self.period]
         start = 0
         for i in range(1, len(kinds) + 1):
             if i == len(kinds) or kinds[i] != kinds[start]:
@@ -255,14 +303,16 @@ class ModelConfig:
 class DecodeState:
     """Per-request decoding state (stacked over layers for scanning)."""
 
-    kv: Optional[Dict[str, jax.Array]]  # k/v: [L, B, S_max, Hkv, Dh]
+    #: k/v: [L, B, S_max, Hkv, Dh]; MLA stacks: c_kv [L, B, S_max, r] and
+    #: k_pe [L, B, S_max, rope]
+    kv: Optional[Dict[str, jax.Array]]
     ssm: Optional[Dict[str, jax.Array]]  # h: [L,B,H,P,N]; conv: [L,B,K-1,C]
     cross_kv: Optional[Dict[str, jax.Array]]  # whisper: [L,B,T_enc,Hkv,Dh]
     length: jax.Array  # [] int32: tokens already decoded
-    #: interleaved stacks: per layer, the held experts the last step's
-    #: tokens were routed to ([L] int32).  Interleaved stacks also keep K/V
-    #: of their attention layers alone (L = attention layers) and SSM
-    #: state of their Mamba layers alone (L = Mamba layers).
+    #: interleaved stacks: per scanned layer, the held experts the last
+    #: step's tokens were routed to ([L] int32).  Interleaved stacks also
+    #: keep K/V (or the latent cache) of their attention (or MLA) layers
+    #: alone and SSM state of their Mamba layers alone.
     experts_touched: Optional[jax.Array] = None
 
 
@@ -371,10 +421,12 @@ class TransformerLM:
         return self._sublayer_init(key, cfg.n_scan, ffn=ffn, d_ff=cfg.d_ff,
                                    cross=cross)
 
-    def _kind_init(self, key, kind: str, count: int) -> Tuple[Params, Axes]:
+    def _kind_init(self, key, kind: str, count: int,
+                   dense: bool = False) -> Tuple[Params, Axes]:
         """``count`` stacked layers of one kind of an interleaved stack:
-        the mixer (Mamba-2 or attention), then the expert layer with its
-        shared MLP, each after an RMSNorm."""
+        the mixer (Mamba-2, attention or MLA), then the expert layer with
+        its shared MLP (``dense``: a SwiGLU of ``d_ff_dense``), each after
+        an RMSNorm."""
         cfg = self.cfg
         km, ke = jax.random.split(key)
         norm = lambda: jnp.zeros((count, cfg.d_model), cfg.dtype)  # noqa: E731
@@ -384,13 +436,23 @@ class TransformerLM:
         if kind == "mamba":
             params["ssm"], axes["ssm"] = ssm_lib.ssm_init(
                 km, cfg.d_model, cfg.ssm_dims, cfg.dtype, stacked=count)
+        elif kind == "mla":
+            params["mla"], axes["mla"] = attn.mla_init(
+                km, cfg.d_model, cfg.n_q_heads,
+                kv_lora_rank=cfg.kv_lora_rank,
+                nope_dim=cfg.qk_nope_head_dim, rope_dim=cfg.qk_rope_head_dim,
+                v_dim=cfg.v_head_dim, dtype=cfg.dtype, stacked=count)
         else:
             params["attn"], axes["attn"] = attn.attention_init(
                 km, cfg.d_model, cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim,
                 cfg.dtype, stacked=count, qkv_bias=cfg.qkv_bias)
-        params["moe"], axes["moe"] = moe_lib.held_moe_init(
-            ke, cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held, cfg.dtype,
-            stacked=count, shared_expert_ff=cfg.shared_expert_ff)
+        if dense:
+            params["mlp"], axes["mlp"] = mlp_init(
+                ke, cfg.d_model, cfg.d_ff_dense, cfg.dtype, stacked=count)
+        else:
+            params["moe"], axes["moe"] = moe_lib.held_moe_init(
+                ke, cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held, cfg.dtype,
+                stacked=count, shared_expert_ff=cfg.shared_expert_ff)
         return params, axes
 
     def init(self, key) -> Tuple[Params, Axes]:
@@ -402,11 +464,16 @@ class TransformerLM:
         axes["embed"] = ("vocab", "embed")
         if cfg.interleaved:
             params["layers"], axes["layers"] = {}, {}
-            for i, kind in enumerate(("mamba", "attention")):
-                if cfg.kind_count(kind):
+            for i, kind in enumerate(("mamba", "attention", "mla")):
+                n = sum(k == kind for k in cfg.scanned_types)
+                if n:
                     params["layers"][kind], axes["layers"][kind] = \
                         self._kind_init(jax.random.fold_in(k_layers, i),
-                                        kind, cfg.kind_count(kind))
+                                        kind, n)
+            if cfg.n_dense_lead:
+                params["lead"], axes["lead"] = self._kind_init(
+                    jax.random.fold_in(k_layers, 3), cfg.layer_types[0],
+                    cfg.n_dense_lead, dense=True)
         else:
             params["layers"], axes["layers"] = self._layer_init(
                 k_layers, cross=cfg.n_encoder_layers > 0
@@ -685,8 +752,11 @@ class TransformerLM:
             "conv": ("layers", "batch", "conv", "ssm_conv_dim"),
         }
         if cfg.interleaved:
+            if cfg.kv_kind == "mla":
+                kv_ax = {"c_kv": ("layers", "batch", "kv_seq", "cache_dim"),
+                         "k_pe": ("layers", "batch", "kv_seq", "cache_dim")}
             return DecodeState(
-                kv=kv_ax if cfg.kind_count("attention") else None,
+                kv=kv_ax if cfg.kind_count(cfg.kv_kind) else None,
                 ssm=ssm_ax if cfg.kind_count("mamba") else None,
                 cross_kv=None, length=("batch",),
                 experts_touched=("layers",),
@@ -850,12 +920,12 @@ class TransformerLM:
         x = self._embed_inputs(params, tokens, frontend_embeds)
         if cfg.interleaved:
             x, st, touched = self._il_full(
-                params, x, positions, {"attention": state.kv,
+                params, x, positions, {cfg.kv_kind: state.kv,
                                        "mamba": state.ssm})
             x = self._final_norm(params, x)
             logits = self._logits(params, x[:, -1:, :])[:, 0, :]
             return logits, DecodeState(
-                kv=st["attention"], ssm=st["mamba"], cross_kv=None,
+                kv=st[cfg.kv_kind], ssm=st["mamba"], cross_kv=None,
                 length=jnp.full((b,), s, jnp.int32), experts_touched=touched)
         windows = cfg.window_sizes()
         memory_kv = None
@@ -920,11 +990,17 @@ class TransformerLM:
     def _il_init_state(self, batch: int, max_len: int) -> DecodeState:
         cfg = self.cfg
         n_attn, n_mamba = cfg.kind_count("attention"), cfg.kind_count("mamba")
+        n_mla = cfg.kind_count("mla")
         kv = ssm_state = None
         if n_attn:
             shape = (n_attn, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
             kv = {"k": jnp.zeros(shape, cfg.dtype),
                   "v": jnp.zeros(shape, cfg.dtype)}
+        if n_mla:
+            kv = {"c_kv": jnp.zeros((n_mla, batch, max_len, cfg.kv_lora_rank),
+                                    cfg.dtype),
+                  "k_pe": jnp.zeros((n_mla, batch, max_len,
+                                     cfg.qk_rope_head_dim), cfg.dtype)}
         if n_mamba:
             dims = cfg.ssm_dims
             ssm_state = {
@@ -935,23 +1011,38 @@ class TransformerLM:
             }
         return DecodeState(kv=kv, ssm=ssm_state, cross_kv=None,
                            length=jnp.zeros((batch,), jnp.int32),
-                           experts_touched=jnp.zeros((cfg.n_layers,),
-                                                     jnp.int32))
+                           experts_touched=jnp.zeros(
+                               (len(cfg.scanned_types),), jnp.int32))
 
-    def _il_stack(self, layers: Params, x: jax.Array, state: Dict[str, Any],
+    def _il_stack(self, params: Params, x: jax.Array, state: Dict[str, Any],
                   mixer):
-        """Run an interleaved stack: a scan over its periods, and in each an
-        inner scan per run of one kind.  Each layer is an RMSNorm, its
-        mixer ``mixer(kind, layer_params, h, kind_state, index_in_kind) ->
-        (out, kind_state)`` added to the residual times
-        ``residual_multiplier``, then the expert layer.  ``state`` maps
-        each kind to its stacked state (or None), carried and updated in
-        place.  Returns (x, state, held experts touched [n_layers])."""
+        """Run an interleaved stack: its leading dense layers, then a scan
+        over its periods, and in each an inner scan per run of one kind.
+        Each layer is an RMSNorm, its mixer ``mixer(kind, layer_params, h,
+        kind_state, index_in_kind) -> (out, kind_state)`` added to the
+        residual times ``residual_multiplier``, then the expert layer (a
+        leading layer's dense SwiGLU).  ``state`` maps each kind to its
+        stacked state (or None), carried and updated in place.  Returns
+        (x, state, held experts touched [scanned layers])."""
         cfg = self.cfg
-        per_kind = {k: sum(t == k for t in cfg.layer_types[:cfg.period])
-                    for k in ("mamba", "attention")}
-        touched0 = jnp.zeros((cfg.n_layers,), jnp.int32)
+        layers = params["layers"]
+        kinds = cfg.scanned_types
+        per_kind = {k: sum(t == k for t in kinds[:cfg.period])
+                    for k in set(kinds)}
+        touched0 = jnp.zeros((len(kinds),), jnp.int32)
         res = cfg.residual_multiplier
+        # a kind's state index, counted past its leading dense layers
+        past = {k: cfg.layer_types[:cfg.n_dense_lead].count(k)
+                for k in per_kind}
+        for j, kind in enumerate(cfg.layer_types[:cfg.n_dense_lead]):
+            lp = jax.tree.map(lambda a, j=j: a[j], params["lead"])
+            h = rmsnorm(x, lp["pre_norm"], cfg.norm_eps)
+            out, st_k = mixer(kind, lp, h, state[kind], j)
+            x = x + out * jnp.asarray(res, x.dtype)
+            h = rmsnorm(x, lp["post_norm"], cfg.norm_eps)
+            m = mlp_apply(lp["mlp"], h, activation=cfg.activation)
+            x = x + m * jnp.asarray(res, x.dtype)
+            state = {**state, kind: st_k}
 
         def period_body(carry, p):
             for kind, first, count, at in cfg.segments():
@@ -963,7 +1054,7 @@ class TransformerLM:
                             a, i, keepdims=False), layers[kind])
                     x1 = constrain(x1, ("batch", "seq", "embed_act"))
                     h = rmsnorm(x1, lp["pre_norm"], cfg.norm_eps)
-                    out, st_k = mixer(kind, lp, h, st[kind], i)
+                    out, st_k = mixer(kind, lp, h, st[kind], i + past[kind])
                     x1 = x1 + out * jnp.asarray(res, x1.dtype)
                     x1, t = self._il_ffn(lp, x1)
                     touched = touched.at[p * cfg.period + at + j].set(t)
@@ -975,7 +1066,7 @@ class TransformerLM:
 
         (x, state, touched), _ = jax.lax.scan(
             period_body, (x, state, touched0),
-            jnp.arange(cfg.n_layers // cfg.period))
+            jnp.arange(len(kinds) // cfg.period))
         return x, state, touched
 
     def _il_ffn(self, lp: Params, x: jax.Array):
@@ -984,14 +1075,15 @@ class TransformerLM:
         with jax.named_scope("moe"):
             m, touched = moe_lib.held_moe_apply(
                 lp["moe"], h, top_k=cfg.top_k, held=cfg.held,
-                activation=cfg.activation)
+                activation=cfg.activation, router=cfg.router)
         return x + m * jnp.asarray(cfg.residual_multiplier, x.dtype), touched
 
     def _il_full(self, params: Params, x: jax.Array, positions: jax.Array,
                  state: Optional[Dict[str, Any]]):
         """Whole-sequence pass (training forward, or prefill when ``state``
-        holds the stacked K/V and SSM state to fill)."""
+        holds the stacked K/V (or latent) and SSM state to fill)."""
         cfg = self.cfg
+        mla = cfg.mla_args() if "mla" in cfg.layer_types else {}
 
         def mixer(kind, lp, h, st, i):
             if kind == "mamba":
@@ -1001,6 +1093,10 @@ class TransformerLM:
                 if st is not None:
                     st = {k: jax.lax.dynamic_update_index_in_dim(
                         st[k], new[k].astype(st[k].dtype), i, 0) for k in st}
+            elif kind == "mla":
+                with jax.named_scope("attn"):
+                    out, st = attn.mla_full(lp["mla"], h, positions, st, i,
+                                            q_block=IL_Q_BLOCK, **mla)
             else:
                 with jax.named_scope("attn"):
                     if st is not None:
@@ -1015,8 +1111,8 @@ class TransformerLM:
                         query_scale=cfg.query_scale, q_block=IL_Q_BLOCK)
             return out, st
 
-        st0 = state or {"mamba": None, "attention": None}
-        x, st, touched = self._il_stack(params["layers"], x, st0, mixer)
+        st0 = state or {"mamba": None, cfg.kv_kind: None}
+        x, st, touched = self._il_stack(params, x, st0, mixer)
         return x, (st if state is not None else None), touched
 
     def _il_decode_step(self, params: Params, state: DecodeState,
@@ -1024,11 +1120,16 @@ class TransformerLM:
         cfg = self.cfg
         length = state.length
         x = self._embed_inputs(params, token[:, None], None)  # [B,1,D]
+        mla = cfg.mla_args() if "mla" in cfg.layer_types else {}
 
         def mixer(kind, lp, h, st, i):
             if kind == "mamba":
                 with jax.named_scope("ssm"):
                     out, st = self._ssm_decode(lp, h, st, i, cfg.norm_eps)
+            elif kind == "mla":
+                with jax.named_scope("attn"):
+                    out, st = attn.mla_cached(lp["mla"], h, st, i, length,
+                                              **mla)
             else:
                 with jax.named_scope("attn"):
                     out, st = attn.attend_cached_stacked(
@@ -1037,11 +1138,10 @@ class TransformerLM:
             return out, st
 
         x, st, touched = self._il_stack(
-            params["layers"], x, {"attention": state.kv, "mamba": state.ssm},
-            mixer)
+            params, x, {cfg.kv_kind: state.kv, "mamba": state.ssm}, mixer)
         x = self._final_norm(params, x)
         logits = self._logits(params, x)[:, 0, :]
-        return logits, DecodeState(kv=st["attention"], ssm=st["mamba"],
+        return logits, DecodeState(kv=st[cfg.kv_kind], ssm=st["mamba"],
                                    cross_kv=None, length=length + 1,
                                    experts_touched=touched)
 

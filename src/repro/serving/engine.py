@@ -55,6 +55,34 @@ def _read(x: Any) -> Any:
     return jax.device_get(x)
 
 
+def kv_bytes_per_token(state: DecodeState) -> int:
+    """Bytes of one slot's per-position cache at one position, summed over
+    the state's ``kv`` leaves ([L, B, S_max, ...]): K and V of the
+    attention layers, or MLA's latent and rope key."""
+    return sum(x.size // (x.shape[1] * x.shape[2]) * x.dtype.itemsize
+               for x in jax.tree.leaves(state.kv))
+
+
+def insert_slot(state: DecodeState, state1: DecodeState, slot: jax.Array,
+                plen: jax.Array) -> DecodeState:
+    """``state`` with slot ``slot`` set to the one-slot prefill state
+    ``state1`` of a ``plen``-token prompt.  Jitted with ``state`` donated,
+    it writes that slot where the state lies, and every other slot is left
+    as it was."""
+    def put(dst, src):
+        return jax.lax.dynamic_update_slice_in_dim(dst, src.astype(dst.dtype),
+                                                   slot, axis=1)
+
+    kv, ssm = state.kv, state.ssm
+    if kv is not None:
+        kv = {k: put(kv[k], state1.kv[k]) for k in kv}
+    if ssm is not None:
+        ssm = {k: put(ssm[k], state1.ssm[k]) for k in ssm}
+    return DecodeState(kv=kv, ssm=ssm, cross_kv=state.cross_kv,
+                       length=state.length.at[slot].set(plen),
+                       experts_touched=state.experts_touched)
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -113,23 +141,18 @@ class ServingEngine:
         #: host mirror of ``state.length``: set at admission, +1 per step
         self._lengths = np.zeros((cfg.max_slots,), np.int32)
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
+        self._insert = jax.jit(insert_slot, donate_argnums=(0,))
         self._prefill_cache: Dict[int, Callable] = {}
 
-        # Tier accounting constants, by layer kind: K/V rows of the
-        # attention layers per token, and the fixed-size recurrent state
-        # (SSM ``h`` and conv window) of the Mamba layers per slot.
+        # Tier accounting constants, from the state's own leaves: the rows
+        # of the per-position cache (K/V of attention layers, or MLA's
+        # latent and rope key) per token, and the fixed-size recurrent
+        # state (SSM ``h`` and conv window) of the Mamba layers per slot.
         self.param_bytes = sum(
             int(np.prod(x.shape)) * x.dtype.itemsize
             for x in jax.tree.leaves(self.params)
         )
-        cfgm = cfg.model
-        if cfgm.interleaved:
-            n_attn = cfgm.kind_count("attention")
-        else:
-            n_attn = cfgm.n_layers if cfgm.uses_attention else 0
-        self.kv_bytes_per_token = (
-            2 * cfgm.n_kv_heads * cfgm.head_dim * n_attn * 2
-        )
+        self.kv_bytes_per_token = kv_bytes_per_token(self.state)
         self.ssm_bytes_per_slot = sum(
             x.size // x.shape[1] * x.dtype.itemsize
             for x in jax.tree.leaves(self.state.ssm)
@@ -176,21 +199,9 @@ class ServingEngine:
 
     def _insert_state(self, slot: int, state1: DecodeState,
                       plen: int) -> None:
-        def put(dst, src):
-            return dst.at[:, slot].set(src[:, 0])
-
-        st = self.state
-        kv = st.kv
-        if kv is not None:
-            kv = {k: put(kv[k], state1.kv[k]) for k in kv}
-        ssm = st.ssm
-        if ssm is not None:
-            ssm = {k: put(ssm[k], state1.ssm[k]) for k in ssm}
-        length = st.length.at[slot].set(plen)
+        self.state = self._insert(self.state, state1, jnp.int32(slot),
+                                  jnp.int32(plen))
         self._lengths[slot] = plen
-        self.state = DecodeState(kv=kv, ssm=ssm, cross_kv=st.cross_kv,
-                                 length=length,
-                                 experts_touched=st.experts_touched)
 
     def admit(self, now_ns: float) -> List[Tuple[Request, int]]:
         """Prefill queued requests into free slots.  Returns admissions

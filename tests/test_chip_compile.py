@@ -134,3 +134,60 @@ def test_qwen_decode_updates_its_cache_in_place(one_chip):
                       for x in jax.tree.leaves(state))
     assert mem.alias_size_in_bytes >= state_bytes - 2 ** 20
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def _deepseek_share(one_chip, slots=16, max_len=12288):
+    """The deepseek-v2-lite share the chip benchmark serves (all 27
+    layers, experts 0-7 of 64): its model, weights and serving state."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite").config,
+                              experts_held=(0, 8))
+    model = TransformerLM(cfg)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))[0]))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: model.init_decode_state(slots, max_len)))
+    return model, params, state
+
+
+def _nbytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def test_deepseek_share_serving_fits_one_v5e(one_chip):
+    """The share's weights (6.22 GB), its latent cache at 16 slots of
+    12288 positions (6.12 GB) and its largest step, an 8192-token prefill
+    with its one-slot state, fit one chip's HBM together; the decode
+    step's donated state is aliased whole, and the step holds no second
+    copy of the cache (the latent cache is read where it lies)."""
+    model, params, state = _deepseek_share(one_chip)
+    token = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    dec = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, state, token).compile().memory_analysis()
+    assert dec.alias_size_in_bytes >= _nbytes(state) - 2 ** 20
+    assert dec.temp_size_in_bytes < 64 * 2 ** 20
+
+    def prefill(p, tokens):
+        return model.prefill(p, tokens, model.init_decode_state(1, 12288))
+
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    pre = jax.jit(prefill).lower(params, tokens).compile().memory_analysis()
+    assert _nbytes(params) + _nbytes(state) + pre.temp_size_in_bytes \
+        + pre.output_size_in_bytes <= V5E_HBM_BYTES
+
+
+def test_deepseek_insert_writes_one_slot_in_place(one_chip):
+    """The engine's admission (``insert_slot``, jitted with the state
+    donated) writes the admitted slot where the state lies: the whole
+    latent cache is aliased, and the program holds no copy of it."""
+    from repro.serving.engine import insert_slot
+
+    model, _, state = _deepseek_share(one_chip)
+    state1 = _on(one_chip, jax.eval_shape(
+        lambda: model.init_decode_state(1, 12288)))
+    index = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    mem = jax.jit(insert_slot, donate_argnums=(0,)).lower(
+        state, state1, index, index).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes(state) - 2 ** 20
+    assert mem.temp_size_in_bytes < 2 ** 20

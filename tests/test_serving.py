@@ -134,3 +134,76 @@ def test_one_host_read_per_decode_step_and_admission():
             logits = fwd(params, jnp.asarray([seq], jnp.int32))
             seq.append(int(jnp.argmax(logits[0, -1])))
         assert r.output == seq[plen:], (r.rid, r.output, seq[plen:])
+
+
+def _smoke_engine(arch, slots=3):
+    cfg = get_arch(arch).smoke
+    model = TransformerLM(cfg)
+    params = jax.jit(lambda k: model.init(k)[0])(jax.random.PRNGKey(0))
+    return ServingEngine(EngineConfig(name="e", model=cfg, max_slots=slots,
+                                      max_len=48), params)
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "granite-4.0-h-small",
+                                  "deepseek-v2-lite"],
+                         ids=["uniform", "interleaved", "mla"])
+def test_admission_leaves_every_other_slot_unchanged(arch):
+    """The jitted, donated insert writes the admitted slot alone: after two
+    slots have decoded, a third request's admission leaves their state
+    bit for bit as it was and puts the prefill's state in its own slot."""
+    import numpy as np
+
+    eng = _smoke_engine(arch)
+    for i, plen in enumerate([5, 9]):
+        eng.submit(Request(rid=i, prompt=list(range(1, plen + 1)),
+                           max_new_tokens=20))
+    eng.admit(0.0)
+    for _ in range(3):
+        eng.decode_once(0.0)
+    before = jax.tree.map(np.asarray, eng.state)
+    prompt = [3, 1, 4, 1, 5, 9, 2]
+    _, state1 = eng._prefill_fn(len(prompt))(
+        eng.params, jnp.asarray([prompt], jnp.int32))
+    req = Request(rid=2, prompt=prompt, max_new_tokens=4)
+    eng.submit(req)
+    eng.admit(0.0)
+    assert eng.slot_req[2] is req
+    after = jax.tree.map(np.asarray, eng.state)
+    for name in ("kv", "ssm"):
+        for k in (getattr(before, name) or {}):
+            old, new = getattr(before, name)[k], getattr(after, name)[k]
+            np.testing.assert_array_equal(new[:, :2], old[:, :2])
+            np.testing.assert_array_equal(
+                new[:, 2:], np.asarray(getattr(state1, name)[k]))
+    assert list(after.length) == [int(before.length[0]),
+                                  int(before.length[1]), len(prompt)]
+    assert list(eng._lengths) == list(after.length)
+
+
+def test_tier_charge_follows_the_state_leaves():
+    """K/V bytes per token are read off the state's per-position leaves:
+    K and V of every attention layer (unchanged for the uniform and
+    interleaved stacks), and MLA's latent and rope key, 576 bf16 values
+    in each of 27 layers (31,104 B) at the published widths."""
+    import dataclasses as dc
+
+    from repro.serving.engine import kv_bytes_per_token
+
+    for arch, n_attn in (("qwen2.5-3b", 36), ("granite-4.0-h-small", 4)):
+        cfg = get_arch(arch).config
+        st = jax.eval_shape(
+            lambda c=cfg: TransformerLM(c).init_decode_state(2, 16))
+        assert kv_bytes_per_token(st) == \
+            2 * cfg.n_kv_heads * cfg.head_dim * n_attn * 2
+    ds = dc.replace(get_arch("deepseek-v2-lite").config, experts_held=(0, 8))
+    st = jax.eval_shape(lambda: TransformerLM(ds).init_decode_state(2, 16))
+    assert kv_bytes_per_token(st) == 27 * (512 + 64) * 2 == 31_104
+    eng = _smoke_engine("deepseek-v2-lite", slots=2)
+    smoke = get_arch("deepseek-v2-lite").smoke
+    per_token = smoke.n_layers * (smoke.kv_lora_rank
+                                  + smoke.qk_rope_head_dim) * 2
+    assert eng.kv_bytes_per_token == per_token and eng.ssm_bytes_per_slot == 0
+    eng.submit(Request(rid=0, prompt=[1, 2, 3, 4, 5], max_new_tokens=3))
+    eng.admit(0.0)
+    eng.decode_once(0.0)
+    assert eng.step_bytes() == (eng.param_bytes, 6 * per_token)
